@@ -799,7 +799,7 @@ def ivf_sharded_bench(scale="ci", batch=64, k=10, n=32,
     """
     from functools import partial
 
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.core.similarity import dense_similarity
@@ -853,7 +853,7 @@ def ivf_sharded_bench(scale="ci", batch=64, k=10, n=32,
         return shard_map(inner, mesh=mesh,
                          in_specs=(P(None, None), P("data", None)),
                          out_specs=(P(None, None), P(None, None)),
-                         check_rep=False)(q, cand)
+                         check_vma=False)(q, cand)
 
     vs, is_ = mesh_stream(new_rep, cand)
 

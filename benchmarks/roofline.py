@@ -1,8 +1,10 @@
 """Roofline derivation from the dry-run artifacts (EXPERIMENTS.md §Roofline).
 
-    t_compute    = HLO_FLOPs / (chips · 197e12)        [bf16 peak, v5e]
-    t_memory     = HLO_bytes / (chips · 819e9)         [HBM BW]
-    t_collective = collective_bytes / (chips · 50e9)   [ICI per link]
+    t_compute    = HLO_FLOPs / peak bf16 FLOP/s
+    t_memory     = HLO_bytes / peak HBM bytes/s
+    t_collective = collective_bytes / peak ICI bytes/s
+
+with the per-chip peaks of the record's ``device_kind`` (:data:`PEAKS`).
 
 ``cost_analysis()`` numbers from the host-CPU dry-run are per-*device*
 programs, so `chips` is already factored out of flops/bytes; collective bytes
@@ -21,9 +23,22 @@ import json
 from pathlib import Path
 from typing import Dict, Optional
 
-PEAK_FLOPS = 197e12  # bf16 / chip
-HBM_BW = 819e9  # B/s / chip
-ICI_BW = 50e9  # B/s / link
+# Published per-chip peaks, keyed by ``jax.Device.device_kind``. Source:
+# Google Cloud documentation, "TPU v5e" (197 TFLOP/s bf16, 393 TOP/s int8,
+# 819 GB/s HBM, 1,600 Gbit/s inter-chip interconnect).
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"bf16_flops": 197e12, "hbm_bytes": 819e9,
+                    "ici_bytes": 1600e9 / 8},
+}
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """The published peaks of one chip of ``device_kind``; unknown kinds raise."""
+    if device_kind not in PEAKS:
+        raise ValueError(f"no published peaks for device kind {device_kind!r}; "
+                         f"known: {sorted(PEAKS)}")
+    return PEAKS[device_kind]
+
 
 # tokens (or equivalent work items) per step, for MODEL_FLOPS
 def model_flops(arch_name: str, shape: str, variant: str = "base") -> Optional[float]:
@@ -90,9 +105,10 @@ def derive(record: Dict, calibration: Optional[Dict] = None) -> Dict:
         flops = max(record["flops"], 0.0)
         bytes_acc = max(record["bytes_accessed"], 0.0)
     coll_bytes = sum(coll.values())
-    t_c = flops / PEAK_FLOPS
-    t_m = bytes_acc / HBM_BW
-    t_n = coll_bytes / ICI_BW
+    peak = peaks(record["device_kind"])
+    t_c = flops / peak["bf16_flops"]
+    t_m = bytes_acc / peak["hbm_bytes"]
+    t_n = coll_bytes / peak["ici_bytes"]
     dominant = max((t_c, "compute"), (t_m, "memory"), (t_n, "collective"))[1]
     mf = model_flops(record["arch"], record["shape"], record.get("variant", "base"))
     chips = record["n_devices"]

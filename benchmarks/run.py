@@ -4,23 +4,26 @@ Prints ``name,us_per_call,derived`` CSV rows. ``--full`` widens the sweeps to
 the 1M-rating datasets (slower); default keeps a CPU-friendly budget.
 Roofline rows are appended when the dry-run JSON artifacts exist (exp/).
 
-Every family runs behind a guard: a row whose optional deps or backends are
-unavailable (multi-device runtime, hypothesis, roofline artifacts, a backend
-that only exists on TPU, ...) emits a ``<name>[skipped]`` row with the reason
-instead of aborting the whole run — partial runs still produce the complete
-CSV, and ``--json PATH`` still writes a valid JSON row dump.
+Every family runs behind a guard. A family whose optional import is missing
+emits a ``<name>[skipped]`` row; one that fails for any other reason emits a
+``<name>[failed]`` row, the remaining families still run, and the run then
+exits non-zero. ``--json PATH`` writes the rows either way.
 """
 from __future__ import annotations
 
 import argparse
 import json
 import time
+import traceback
 from pathlib import Path
 from typing import List
+
+from repro.launch.compile_cache import use_compile_cache
 
 from . import paper_tables
 
 ROWS: List[dict] = []
+FAILED: List[str] = []
 
 
 def _emit(name: str, us: float, derived: str = ""):
@@ -29,12 +32,16 @@ def _emit(name: str, us: float, derived: str = ""):
 
 
 def _guard(label: str, fn) -> None:
-    """Run one bench family; emit a [skipped] row instead of crashing when
-    its optional deps/backends are missing on this host."""
+    """Run one bench family. A missing optional import skips it; any other
+    failure is printed, recorded, and fails the run once every family ran."""
     try:
         fn()
-    except Exception as e:  # noqa: BLE001 — any family failure is a skip
+    except ImportError as e:
         _emit(f"{label}[skipped]", 0.0, f"{type(e).__name__}: {e}")
+    except Exception as e:  # noqa: BLE001 — recorded; main exits non-zero
+        traceback.print_exc()
+        _emit(f"{label}[failed]", 0.0, f"{type(e).__name__}: {e}")
+        FAILED.append(label)
 
 
 def _bench_fig2(datasets, full):
@@ -365,6 +372,7 @@ def main(argv=None) -> None:
                     help="also write the emitted rows as a JSON list; "
                     "skipped rows are included, so partial runs stay valid")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     print("name,us_per_call,derived")
     if args.sharded_only:
@@ -443,6 +451,8 @@ def main(argv=None) -> None:
 
     if args.json:
         Path(args.json).write_text(json.dumps(ROWS, indent=2) + "\n")
+    if FAILED:
+        raise SystemExit(f"bench families failed: {', '.join(FAILED)}")
 
 
 if __name__ == "__main__":

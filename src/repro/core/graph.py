@@ -469,7 +469,7 @@ def extend_neighbor_graph_sharded(
     survives the mesh. Oracle-exact vs the single-device bucketed fold-in
     modulo the dense↔sharded id bijection (tests/test_sharded_serving.py).
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.distributed.sharding import cf_row_axes, cf_shard_count, \
@@ -539,7 +539,7 @@ def extend_neighbor_graph_sharded(
     gi, gw = shard_map(
         inner, mesh=mesh,
         in_specs=(row, row, row, P(axes), P(None, None), P(None), P(), P()),
-        out_specs=(row, row), check_rep=False,
+        out_specs=(row, row), check_vma=False,
     )(graph.indices, graph.weights, rep, row_rank, new_rep, n_valid, b_valid,
       target_shard)
     return NeighborGraph(gi, gw)

@@ -81,8 +81,11 @@ def _block_predict(idx, w, centered, mask, mu):
     """Eq. (1) for one user block given its (block, k) neighbor lists."""
     nb_centered = centered[idx]  # gathers: (block, k, P)
     nb_mask = mask[idx]
-    num = jnp.einsum("bk,bkp->bp", w, nb_centered)
-    den = jnp.einsum("bk,bkp->bp", jnp.abs(w), nb_mask)
+    # HIGHEST: at DEFAULT precision the TPU feeds f32 operands to the MXU
+    # as bf16, an error of ~1e-2 in a rating prediction
+    hi = jax.lax.Precision.HIGHEST
+    num = jnp.einsum("bk,bkp->bp", w, nb_centered, precision=hi)
+    den = jnp.einsum("bk,bkp->bp", jnp.abs(w), nb_mask, precision=hi)
     return mu[:, None] + num / jnp.maximum(den, EPS)
 
 

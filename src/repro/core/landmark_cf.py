@@ -400,7 +400,7 @@ def fit_distributed(
         return masked_similarity(r, lm, spec.d1)  # local GEMMs
 
     rep = _rep(jax.device_put(r_pad, user_sharding), landmarks)
-    with mesh:
+    with jax.set_mesh(mesh):
         vals, nbrs = jax.jit(
             lambda rp: streaming_knn_graph_sharded(
                 rp, mesh, spec.d2, k=k, chunk_local=chunk_local, row_axes=axes,

@@ -215,7 +215,7 @@ def streaming_knn_graph_sharded(
     ``n_valid`` (static) marks trailing global rows as padding (ragged U
     rounded up to the shard count): they are never selected as candidates,
     and their own query rows are garbage the caller slices off."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     axes = tuple(a for a in row_axes if a in mesh.axis_names)
@@ -269,5 +269,5 @@ def streaming_knn_graph_sharded(
         inner, mesh=mesh,
         in_specs=(P(axes, None),),
         out_specs=(P(axes, None), P(axes, None)),
-        check_rep=False,
+        check_vma=False,
     )(rep)

@@ -16,7 +16,7 @@ from typing import Any, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -77,4 +77,4 @@ def psum_compressed(x: jax.Array, mesh, axis: str = "pod"):
 
     spec = P(*([None] * x.ndim))
     return shard_map(inner, mesh=mesh, in_specs=(spec,), out_specs=spec,
-                     check_rep=False)(x)
+                     check_vma=False)(x)

@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 
 def _local_lookup(table_shard: jax.Array, ids: jax.Array, axis: str) -> jax.Array:
@@ -62,7 +62,7 @@ def embedding_lookup(
         mesh=mesh,
         in_specs=(P(row_axis, None), id_spec),
         out_specs=out_spec,
-        check_rep=False,
+        check_vma=False,
     )
     return fn(table, ids)
 

@@ -79,21 +79,12 @@ def tree_shardings(logical_tree, mesh: Mesh, rules: Dict[str, Any]):
 
 
 def constrain(x: jax.Array, logical: LogicalAxes, rules: Dict[str, Any], mesh=None) -> jax.Array:
-    """with_sharding_constraint by logical axes; no-op outside a mesh context."""
-    mesh = mesh or _current_mesh()
-    if mesh is None or mesh.empty:
+    """with_sharding_constraint by logical axes; no-op outside a mesh context
+    (``jax.set_mesh``)."""
+    mesh = mesh or jax.sharding.get_abstract_mesh()
+    if mesh.empty:
         return x
     return jax.lax.with_sharding_constraint(x, sharding_for(logical, mesh, rules))
-
-
-def _current_mesh() -> Optional[Mesh]:
-    try:
-        from jax._src import mesh as mesh_lib
-
-        m = mesh_lib.thread_resources.env.physical_mesh
-        return None if m.empty else m
-    except Exception:
-        return None
 
 
 def divisible(dim: int, axes, mesh: Mesh) -> bool:
@@ -212,7 +203,7 @@ def shard_local_append(x: jax.Array, rows: jax.Array, n_valid: jax.Array,
     ``rows`` (b, ...) replicated, ``n_valid`` the (S,) per-shard fill counts,
     ``target`` a traced scalar. Non-target shards are untouched; no cross-shard
     traffic beyond the already-replicated ``rows``."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     nd = x.ndim
 
@@ -227,7 +218,7 @@ def shard_local_append(x: jax.Array, rows: jax.Array, n_valid: jax.Array,
     return shard_map(
         inner, mesh=mesh,
         in_specs=(row_spec, P(*(None,) * nd), P(None), P()),
-        out_specs=row_spec, check_rep=False,
+        out_specs=row_spec, check_vma=False,
     )(x, rows, n_valid, target)
 
 

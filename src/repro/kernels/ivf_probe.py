@@ -16,7 +16,7 @@ the probed rows and a (b, k) result, nothing else.
   grid = (b, nprobe)            probe rank innermost, arbitrary
   scalar prefetch: probe (b, nprobe), fill (C,), self ids (b,),
                    probe_ok (b, nprobe)
-  VMEM: query row (1, n) + posting block (1, cap, n) [+ scale (1, cap)]
+  VMEM: query row (1, 1, n) + posting block (1, cap, n) [+ scale (1, cap, 1)]
         + best (1, k) ×2 scratch
 
 Exactness: scores use the same HIGHEST-precision dot + measure epilogue as
@@ -32,7 +32,7 @@ kernel visit cells in any probe order.
 
 Quantized payloads (``IVFIndex.payload_dtype``) dequantize in-kernel after
 the block DMA: bf16/int8 shrink the HBM read 2–4x, and the f32 compute path
-is untouched (int8 blocks ride with a (1, cap) f32 scale block).
+is untouched (int8 blocks ride with a (1, cap, 1) f32 scale block).
 
 The probe table must hold *distinct* cells per query (``lax.top_k`` over
 centroid sims guarantees it); a repeated cell would insert its members
@@ -77,6 +77,13 @@ def _probe_sims(q, cand, measure):
     raise ValueError(f"unknown measure {measure!r}")
 
 
+def _first(mask, iota):
+    """(1, 1) index of the first set slot of a (1, w) mask (one is always set).
+
+    A min over the lane iota: Mosaic lowers argmax for f32 operands only."""
+    return jnp.min(jnp.where(mask, iota, INT_MAX), axis=1, keepdims=True)
+
+
 def _kernel(probe_ref, fill_ref, sids_ref, ok_ref, q_ref, lists_ref, rows_ref,
             *rest, k, nprobe, cap, measure, has_scale):
     if has_scale:
@@ -90,12 +97,12 @@ def _kernel(probe_ref, fill_ref, sids_ref, ok_ref, q_ref, lists_ref, rows_ref,
         best_v[...] = jnp.full_like(best_v, -jnp.inf)
         best_i[...] = jnp.full_like(best_i, INT_MAX)
 
-    q = q_ref[...].astype(jnp.float32)  # (1, n)
+    q = q_ref[0].astype(jnp.float32)  # (1, n)
     cand = rows_ref[0].astype(jnp.float32)  # (cap, n) — dequantize post-DMA
     if has_scale:
-        cand = cand * scale_ref[0][:, None]
+        cand = cand * scale_ref[0]  # (cap, 1) column of row scales
     sims = _probe_sims(q, cand, measure)  # (1, cap)
-    ids = lists_ref[...].astype(jnp.int32)  # (1, cap)
+    ids = lists_ref[0]  # (1, cap)
     cell = probe_ref[i, j]
     slot = jax.lax.broadcasted_iota(jnp.int32, (1, cap), 1)
     keep = (slot < fill_ref[cell]) & (ids != sids_ref[i]) & (ok_ref[i, j] != 0)
@@ -116,20 +123,20 @@ def _kernel(probe_ref, fill_ref, sids_ref, ok_ref, q_ref, lists_ref, rows_ref,
         wid = jnp.max(jnp.where(wtie, bi, jnp.iinfo(jnp.int32).min),
                       axis=1, keepdims=True)  # worst = (min value, max id)
         take = (m > vmin) | ((m == vmin) & (sel < wid))  # (1, 1)
-        # first slot holding the worst entry — argmax of the match mask, so
-        # duplicate (-inf, INT_MAX) init entries are displaced one at a time
-        hit = take & (kio == jnp.argmax(wtie & (bi == wid), axis=1)[:, None])
+        # first slot holding the worst entry, so duplicate (-inf, INT_MAX)
+        # init entries are displaced one at a time
+        hit = take & (kio == _first(wtie & (bi == wid), kio))
         bv = jnp.where(hit, m, bv)
         bi = jnp.where(hit, sel, bi)
-        drop = cio == jnp.argmax(tie & (ids == sel), axis=1)[:, None]
+        drop = cio == _first(tie & (ids == sel), cio)
         sims = jnp.where(drop, -jnp.inf, sims)
         ids = jnp.where(drop, INT_MAX, ids)
     best_v[...], best_i[...] = bv, bi
 
     @pl.when(j == nprobe - 1)
     def _done():
-        val_ref[...] = best_v[...]
-        idx_ref[...] = best_i[...]
+        val_ref[0] = best_v[...]
+        idx_ref[0] = best_i[...]
 
 
 def fused_probe_topk(
@@ -165,24 +172,26 @@ def fused_probe_topk(
 
     from jax.experimental.pallas import tpu as pltpu
 
+    # Every operand carries a unit axis ahead of its last two, so each block's
+    # last two dims equal the array's — the TPU tiling rule that a (1, n)
+    # block of a (b, n) array breaks.
+    row = lambda i, j, p, f, s, o: (i, 0, 0)  # noqa: E731
+    cell = lambda i, j, p, f, s, o: (p[i, j], 0, 0)  # noqa: E731
     in_specs = [
-        pl.BlockSpec((1, n), lambda i, j, p, f, s, o: (i, 0)),
-        pl.BlockSpec((1, cap), lambda i, j, p, f, s, o: (p[i, j], 0)),
-        pl.BlockSpec((1, cap, n), lambda i, j, p, f, s, o: (p[i, j], 0, 0)),
+        pl.BlockSpec((1, 1, n), row),
+        pl.BlockSpec((1, 1, cap), cell),
+        pl.BlockSpec((1, cap, n), cell),
     ]
-    inputs = [q.astype(jnp.float32), lists.astype(jnp.int32), rows]
+    inputs = [q.astype(jnp.float32)[:, None, :],
+              lists.astype(jnp.int32)[:, None, :], rows]
     if has_scale:
-        in_specs.append(
-            pl.BlockSpec((1, cap), lambda i, j, p, f, s, o: (p[i, j], 0)))
-        inputs.append(scale)
+        in_specs.append(pl.BlockSpec((1, cap, 1), cell))
+        inputs.append(scale[:, :, None])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=4,
         grid=(b, nprobe),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, k), lambda i, j, p, f, s, o: (i, 0)),
-            pl.BlockSpec((1, k), lambda i, j, p, f, s, o: (i, 0)),
-        ],
+        out_specs=[pl.BlockSpec((1, 1, k), row), pl.BlockSpec((1, 1, k), row)],
         scratch_shapes=[
             pltpu.VMEM((1, k), jnp.float32),
             pltpu.VMEM((1, k), jnp.int32),
@@ -197,13 +206,14 @@ def fused_probe_topk(
                           measure=measure, has_scale=has_scale),
         grid_spec=grid_spec,
         out_shape=[
-            jax.ShapeDtypeStruct((b, k), jnp.float32),
-            jax.ShapeDtypeStruct((b, k), jnp.int32),
+            jax.ShapeDtypeStruct((b, 1, k), jnp.float32),
+            jax.ShapeDtypeStruct((b, 1, k), jnp.int32),
         ],
         interpret=interpret,
         **kwargs,
     )(probe.astype(jnp.int32), fill.astype(jnp.int32),
       self_ids.astype(jnp.int32), ok, *inputs)
+    vals, ids = vals[:, 0], ids[:, 0]
     # canonicalize slot order: two stable argsorts -> (value desc, id asc),
     # the same normalization extend_neighbor_graph_sharded applies to merged
     # lists. -inf slots (id INT_MAX) sink to the tail; surface them as

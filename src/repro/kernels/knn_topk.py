@@ -47,7 +47,10 @@ def _tile_sims(rep, cand, measure):
     if measure == "pearson":
         rep = rep - jnp.mean(rep, axis=1, keepdims=True)
         cand = cand - jnp.mean(cand, axis=1, keepdims=True)
+    # HIGHEST, as dense_similarity: the graph's weights are f32 similarities,
+    # not whatever pass count the MXU defaults to for f32 operands
     z = jax.lax.dot_general(rep, cand, (((1,), (1,)), ((), ())),
+                            precision=jax.lax.Precision.HIGHEST,
                             preferred_element_type=jnp.float32)  # (bu, bc)
     if measure == "cosine":  # caller pre-normalizes rows
         return z

@@ -26,6 +26,10 @@ from repro.configs import registry
 from repro.launch.mesh import make_debug_mesh, make_production_mesh
 from repro.launch.steps import build_cell
 
+# The chip the production meshes model (launch/mesh.py): a v5e pod. The host
+# devices this script compiles on stand in for it.
+TARGET_KIND = "TPU v5 lite"
+
 COLLECTIVE_RE = re.compile(
     r"\b(all-gather|all-reduce|reduce-scatter|all-to-all|collective-permute)\b"
 )
@@ -51,7 +55,6 @@ def run_cell(arch_name: str, shape_name: str, mesh, variant: str = "base",
 
     mem = compiled.memory_analysis()
     cost = compiled.cost_analysis()
-    cost = cost[0] if isinstance(cost, (list, tuple)) else cost
     hlo = compiled.as_text()
     coll = hlo_collective_bytes(hlo)
 
@@ -62,6 +65,7 @@ def run_cell(arch_name: str, shape_name: str, mesh, variant: str = "base",
         "variant": variant,
         "mesh": "x".join(str(mesh.shape[a]) for a in mesh.axis_names),
         "n_devices": int(n_dev),
+        "device_kind": TARGET_KIND,
         "lower_s": round(t_lower, 1),
         "compile_s": round(t_compile, 1),
         "flops": float(cost.get("flops", -1)) if cost else -1.0,

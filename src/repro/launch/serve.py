@@ -29,8 +29,8 @@
   per-shard checkpoint files — with a single-device shadow replay asserting
   every wave's predictions are *bit-identical*, and a jaxpr/sharding check
   proving the fold-in path never materializes a replicated (U, n)
-  representation. On CPU the device count is forced to K·L host devices
-  (CI runs exactly this):
+  representation. Under ``JAX_PLATFORMS=cpu`` the device count is forced
+  to K·L host devices (CI runs exactly this):
   ``python -m repro.launch.serve --workload cf --lifecycle --smoke --mesh pod=2,data=4``
 - ``cf --engine``: open-loop serving through the continuous micro-batching
   request engine (``repro.serving``, docs/serving.md) — a load generator
@@ -67,6 +67,8 @@ from repro import obs as obslib
 from repro.configs import registry
 from repro.data import synthetic as S
 from repro.distributed.sharding import DEFAULT_RULES
+from repro.launch.compile_cache import use_compile_cache
+from repro.launch.mesh import make_mesh
 from repro.models import transformer as lm_mod
 
 
@@ -703,7 +705,7 @@ def _foldin_replication_check(sst, bq, spec):
     def scan(jx, inside):
         for eqn in jx.eqns:
             is_sh = eqn.primitive.name == "shard_map"
-            passthrough = is_sh or eqn.primitive.name == "pjit"
+            passthrough = is_sh or eqn.primitive.name == "jit"
             if eqn.primitive.name == "sharding_constraint":
                 # pinning rows onto the mesh axes keeps them sharded; a
                 # constraint whose row dim is unpartitioned WOULD replicate
@@ -712,7 +714,7 @@ def _foldin_replication_check(sst, bq, spec):
             for v in eqn.outvars:
                 shp = getattr(v.aval, "shape", None) or ()
                 seen.append(shp)
-                # a shard_map/pjit eqn's *result* is the sharded array itself
+                # a shard_map/jit eqn's *result* is the sharded array itself
                 # (their bodies are scanned recursively); any other eqn at
                 # full row size is a materialization
                 if shp and shp[0] >= rows and (inside or not passthrough):
@@ -834,7 +836,7 @@ def _serve_cf_lifecycle_sharded(args):
             f"--mesh {args.mesh} needs {need} devices but jax sees "
             f"{jax.device_count()}; on CPU launch a fresh process (the "
             f"XLA_FLAGS host-platform override must precede jax init)")
-    mesh = jax.make_mesh(sizes, names)
+    mesh = make_mesh(sizes, names)
     axes = names
     n_shards = need
 
@@ -1337,7 +1339,7 @@ def _serve_cf_engine(args):
             raise SystemExit(
                 f"--mesh {args.mesh} needs {need} devices but jax sees "
                 f"{jax.device_count()}")
-        mesh = jax.make_mesh(sizes, names)
+        mesh = make_mesh(sizes, names)
         axes = names
         n_shards = need
         min_shard_bucket = max(8, args.min_bucket // n_shards)
@@ -1950,8 +1952,9 @@ def main(argv=None):
     ap.add_argument("--mesh", default=None,
                     help="lifecycle: run the replay sharded over this mesh, "
                     "e.g. pod=2,data=4 (rows block-partitioned over all "
-                    "listed axes). On CPU the host platform is forced to "
-                    "that many devices, so CI can smoke a pod.")
+                    "listed axes). Under JAX_PLATFORMS=cpu the host "
+                    "platform is forced to that many devices, so CI can "
+                    "smoke a pod.")
     ap.add_argument("--graph-backend", default="auto",
                     choices=("auto", "dense", "streaming", "pallas", "ivf"))
     ap.add_argument("--retrieval", default="exact", choices=("exact", "ivf"),
@@ -2016,15 +2019,18 @@ def main(argv=None):
                          "the request engine (--workload cf --lifecycle / "
                          "--engine); add --mesh to route probes through the "
                          "sharded posting lists")
-    if args.mesh:
-        # must precede first backend use: force a host-platform device count
-        # big enough for the mesh (no-op when XLA_FLAGS already forces one)
+    if args.mesh and os.environ.get("JAX_PLATFORMS") == "cpu":
+        # must precede first backend use: on the CPU platform, force a
+        # host-platform device count big enough for the mesh (no-op when
+        # XLA_FLAGS already forces one). An accelerator run gets no fake
+        # devices: a mesh larger than the chips present fails below.
         _, sizes = _parse_mesh(args.mesh)
         flags = os.environ.get("XLA_FLAGS", "")
         if "device_count" not in flags:
             os.environ["XLA_FLAGS"] = (
                 f"--xla_force_host_platform_device_count="
                 f"{int(np.prod(sizes))} " + flags)
+    use_compile_cache()
     if args.batch is None:
         args.batch = 256 if args.workload == "cf" else 4
     if args.waves is None:

@@ -49,7 +49,7 @@ class Cell:
         )
 
     def lower(self):
-        with self.mesh:
+        with jax.set_mesh(self.mesh):
             return self.jit().lower(*self.args)
 
 
@@ -398,7 +398,7 @@ def _cf_cell(arch: ArchConfig, shape: ShapeSpec, mesh: Mesh, variant: str = "bas
                 # moves as bf16 (2x wire+HBM). Self-exclusion happens outside
                 # the kernel (each shard lacks its global row offset): emit
                 # k+1, mask own ids, re-top-k to k.
-                from jax.experimental.shard_map import shard_map
+                from jax import shard_map
                 from jax.sharding import PartitionSpec as PS
                 from repro.kernels.knn_topk import topk_sim_kernel
 
@@ -413,7 +413,7 @@ def _cf_cell(arch: ArchConfig, shape: ShapeSpec, mesh: Mesh, variant: str = "bas
                     mesh=mesh,
                     in_specs=(PS(baxes, None), PS(None, None)),
                     out_specs=(PS(baxes, None), PS(baxes, None)),
-                    check_rep=False,
+                    check_vma=False,
                 )(repn, repn)
                 vals, nbrs = core_graph.filter_self_from_topk(
                     vals, nbrs, jnp.arange(u), spec.k_neighbors)

@@ -91,7 +91,7 @@ def main(argv=None):
 
     # init real state
     key = jax.random.PRNGKey(0)
-    with mesh:
+    with jax.set_mesh(mesh):
         if arch.family == "lm":
             params = lm_mod.init_lm(key, arch.model)
         elif arch.family == "gnn":
@@ -106,7 +106,7 @@ def main(argv=None):
         opt_state = opt_init(params, arch.opt)
 
     def step_fn(params, opt_state, batch):
-        with mesh:
+        with jax.set_mesh(mesh):
             return step_jit(params, opt_state, batch)
 
     out = train_loop(

@@ -191,7 +191,7 @@ def gnn_forward_shardmap(
     edge lives on its dst's node shard (ownership contract — off-shard dsts
     are masked defensively). node_feats sharded over ('pod','data'); edge
     arrays sharded over all axes."""
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     naxes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)
@@ -252,7 +252,7 @@ def gnn_forward_shardmap(
         inner, mesh=mesh,
         in_specs=(P(naxes, None), P(eaxes), P(eaxes), P(eaxes)),
         out_specs=P(naxes, None),
-        check_rep=False,
+        check_vma=False,
     )(node_feats, edge_src, edge_dst, edge_mask)
 
 

@@ -274,7 +274,7 @@ def repair_sharded(
     compaction preserves it), and the merge re-sorts by rank, so the result
     is the canonical list an oracle build would produce.
     """
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
 
     from repro.distributed.sharding import shard_linear_index
 
@@ -321,7 +321,7 @@ def repair_sharded(
     vals, idx = shard_map(
         inner, mesh=mesh,
         in_specs=(row, P(axes), P(None, None), P(None), P(None), P(None)),
-        out_specs=(P(None, None), P(None, None)), check_rep=False,
+        out_specs=(P(None, None), P(None, None)), check_vma=False,
     )(st.representation, sstate.row_rank, queries, sstate.n_valid,
       msst.tomb, sel)
     fixed = finalize_topk(vals, idx)

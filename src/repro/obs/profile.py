@@ -8,9 +8,10 @@ run":
                            ``jax.profiler`` trace when ``dir`` is set (the
                            serve loop uses it around the warm load window
                            via ``--jax-profile``) and is a no-op
-                           otherwise. Capture failures degrade to a
-                           warning, never a crash — profiling must not be
-                           able to take the serve path down.
+                           otherwise. A trace that was asked for and
+                           cannot start or stop raises: a run that
+                           silently lost its trace would be read as one
+                           that had none to give.
   launch/compile counters  ``count_launch`` bumps per-family launch and
                            row counters (row throughput = rows / wall
                            time); ``publish_compile_counts`` snapshots the
@@ -23,7 +24,6 @@ run":
 from __future__ import annotations
 
 import contextlib
-import sys
 from typing import Dict, Optional
 
 from repro.obs.registry import MetricsRegistry
@@ -32,27 +32,17 @@ from repro.obs.registry import MetricsRegistry
 @contextlib.contextmanager
 def profile_trace(trace_dir: Optional[str]):
     """Capture a ``jax.profiler`` trace into ``trace_dir`` for the duration
-    of the block; yields True iff capture actually started."""
+    of the block; yields True iff a trace was asked for (and started)."""
     if not trace_dir:
         yield False
         return
-    started = False
+    import jax.profiler
+
+    jax.profiler.start_trace(trace_dir)
     try:
-        import jax.profiler
-        jax.profiler.start_trace(trace_dir)
-        started = True
-    except Exception as e:  # missing tensorboard deps, double-start, ...
-        print(f"[obs] jax.profiler capture unavailable: {e}",
-              file=sys.stderr)
-    try:
-        yield started
+        yield True
     finally:
-        if started:
-            try:
-                jax.profiler.stop_trace()
-            except Exception as e:
-                print(f"[obs] jax.profiler stop failed: {e}",
-                      file=sys.stderr)
+        jax.profiler.stop_trace()
 
 
 def count_launch(registry: MetricsRegistry, family: str, rows: int) -> None:

@@ -48,7 +48,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding
 from jax.sharding import PartitionSpec as P
 
@@ -173,7 +173,7 @@ def append_sharded(
                   P(None, None), [P(None)] * len(opt_ps), P(None), P(None),
                   P(None)),
         out_specs=(row2, row3, [row2] * len(opt_scale)),
-        check_rep=False,
+        check_vma=False,
     )(index.lists, index.rows, opt_scale, new_ids.astype(jnp.int32), payload,
       opt_ps, dest_c, dest_s, ok)
     return IVFIndex(index.centroids, lists, rows, new_fill,
@@ -345,7 +345,7 @@ def search_sharded(
                   [row2] * len(opt_scale), P(None),
                   [P(None)] * len(opt_tomb)),
         out_specs=(P(None, None), P(None, None), P(None)),
-        check_rep=False,
+        check_vma=False,
     )(q, probe, sids, index.lists, index.rows, opt_scale, index.fill,
       opt_tomb)
 
@@ -465,6 +465,6 @@ def search_early_exit_sharded(
                   [row2] * len(opt_scale), P(None),
                   [P(None)] * len(opt_tomb)),
         out_specs=(P(None, None), P(None, None), P(None)),
-        check_rep=False,
+        check_vma=False,
     )(q, probe, sids, index.lists, index.rows, opt_scale, index.fill,
       opt_tomb)

@@ -39,7 +39,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro import obs as obslib
@@ -128,7 +128,7 @@ def _predict_pairs_routed(sstate, users: jax.Array, items: jax.Array,
         in_specs=(row2, row2, row2, P(None), P(None), P(None),
                   [P(None)] * len(opt_tomb)),
         out_specs=P(None),
-        check_rep=False,
+        check_vma=False,
     )(graph.indices, graph.weights, sstate.state.ratings, sstate.n_valid,
       users.astype(jnp.int32), items.astype(jnp.int32), opt_tomb)
 
@@ -181,8 +181,9 @@ def _recommend_topn_routed(sstate, users: jax.Array, n: int, tomb=None):
         nb_m = jax.lax.psum(
             jnp.where(own_n[:, :, None], mask_l[slot_n], 0.0), axes)
         # knn._block_predict epilogue, then the never-re-recommend mask
-        num = jnp.einsum("bk,bkp->bp", w, nb_c)
-        den = jnp.einsum("bk,bkp->bp", jnp.abs(w), nb_m)
+        hi = jax.lax.Precision.HIGHEST  # as knn._block_predict
+        num = jnp.einsum("bk,bkp->bp", w, nb_c, precision=hi)
+        den = jnp.einsum("bk,bkp->bp", jnp.abs(w), nb_m, precision=hi)
         preds = mu_q[:, None] + num / jnp.maximum(den, knn.EPS)
         preds = jnp.where(rated > 0, -jnp.inf, preds)
         scores, items = jax.lax.top_k(preds, n)
@@ -194,7 +195,7 @@ def _recommend_topn_routed(sstate, users: jax.Array, n: int, tomb=None):
         in_specs=(row2, row2, row2, P(None), P(None),
                   [P(None)] * len(opt_tomb)),
         out_specs=(P(None, None), P(None, None)),
-        check_rep=False,
+        check_vma=False,
     )(graph.indices, graph.weights, sstate.state.ratings, sstate.n_valid,
       users.astype(jnp.int32), opt_tomb)
 
@@ -209,7 +210,7 @@ recommend_topn_routed._cache_size = _recommend_topn_routed._cache_size
 def materialization_check(sstate, b: int, n: int = 10):
     """Jaxpr proof for the routed request path: trace both routed entry
     points at batch ``b`` and assert no eqn output (i) carries the full
-    ``S*C`` row dimension outside a shard_map/pjit pass-through — a
+    ``S*C`` row dimension outside a shard_map/jit pass-through — a
     replicated row-space materialization — or (ii) is a per-query
     ``(b, >= S*C)`` tensor anywhere, including inside shard_map bodies —
     the dense (b, U) score matrix a gather-based scorer would build.
@@ -236,7 +237,7 @@ def materialization_check(sstate, b: int, n: int = 10):
     def scan(jx, inside):
         for eqn in jx.eqns:
             is_sh = eqn.primitive.name == "shard_map"
-            passthrough = is_sh or eqn.primitive.name == "pjit"
+            passthrough = is_sh or eqn.primitive.name == "jit"
             for v in eqn.outvars:
                 shp = getattr(v.aval, "shape", None) or ()
                 seen.append(shp)

@@ -91,7 +91,7 @@ def test_streaming_knn_sharded_matches_dense_topk(mesh):
     u, n, k = 64, 16, 4
     rep = jnp.asarray(rng.normal(size=(u, n)).astype(np.float32))
     rep_sharded = jax.device_put(rep, NamedSharding(mesh, P(("data",), None)))
-    with mesh:
+    with jax.set_mesh(mesh):
         vals, idx = jax.jit(
             lambda r: streaming_knn_graph_sharded(r, mesh, "cosine", k=k,
                                                   chunk_local=8, row_axes=("data",))
@@ -113,7 +113,7 @@ def test_streaming_knn_sharded_ragged_chunks(mesh):
     u, n, k = 40, 12, 13
     rep = jnp.asarray(rng.normal(size=(u, n)).astype(np.float32))
     rep_sharded = jax.device_put(rep, NamedSharding(mesh, P(("data",), None)))
-    with mesh:
+    with jax.set_mesh(mesh):
         vals, idx = jax.jit(
             lambda r: streaming_knn_graph_sharded(
                 r, mesh, "cosine", k=k, chunk_local=8, row_axes=("data",),
@@ -139,7 +139,7 @@ def test_streaming_knn_sharded_multi_axis_global_ids(mesh, exclude_self):
     rep = jnp.asarray(rng.normal(size=(u, n)).astype(np.float32))
     rep_sharded = jax.device_put(
         rep, NamedSharding(mesh, P(("data", "model"), None)))
-    with mesh:
+    with jax.set_mesh(mesh):
         vals, idx = jax.jit(
             lambda r: streaming_knn_graph_sharded(
                 r, mesh, "cosine", k=k, chunk_local=4,
@@ -161,7 +161,7 @@ def test_streaming_knn_sharded_multi_axis_global_ids(mesh, exclude_self):
 def test_psum_compressed_close_to_exact(mesh):
     rng = np.random.default_rng(3)
     x = jnp.asarray(rng.normal(size=(32, 32)).astype(np.float32))
-    with mesh:
+    with jax.set_mesh(mesh):
         out = psum_compressed(x, mesh, axis="data")
     exact = x * mesh.shape["data"]  # replicated input summed over the axis
     scale = float(jnp.abs(x).max()) / 127.0
@@ -237,7 +237,7 @@ def test_gnn_shardmap_matches_gspmd_reference(mesh):
         masks.append(m)
     src_p, dst_p, mask_p = map(np.concatenate, (srcs, dsts, masks))
 
-    with mesh:
+    with jax.set_mesh(mesh):
         feats_s = jax.device_put(feats, NamedSharding(mesh, P(("data",), None)))
         e_sh = NamedSharding(mesh, P(("data", "model")))
         out = jax.jit(lambda f, s, d, m: gnn_forward_shardmap(

@@ -24,6 +24,7 @@ from repro.core.graph import build_neighbor_graph, canonical_topk, merge_canonic
 from repro.core.landmark_cf import fit
 from repro.core.similarity import masked_similarity
 from repro.core.types import LandmarkSpec, RatingMatrix
+from repro.launch.mesh import make_mesh
 from repro.lifecycle import buckets
 
 U, P = 96, 40
@@ -298,7 +299,7 @@ def test_sharded_mutation_parity():
     to the single-device mutable path (modulo the sharded-id bijection)."""
     from repro.mutation import sharded as muts
 
-    mesh = jax.make_mesh((4,), ("pod",))
+    mesh = make_mesh((4,), ("pod",))
     spec = _spec("pearson")
     r = _ratings(U, P, seed=12)
     st = fit(jax.random.PRNGKey(0), RatingMatrix(r, U, P), spec)
@@ -467,7 +468,7 @@ def test_engine_mutation_kinds_sharded_parity():
     eng.pump_folds()
     gen, table = None, None
 
-    mesh = jax.make_mesh((4,), ("pod",))
+    mesh = make_mesh((4,), ("pod",))
     sstate = buckets.from_state_sharded(st, mesh, row_axes=("pod",),
                                         min_bucket=8)
     u_per = U // 4

@@ -23,6 +23,7 @@ import numpy as np  # noqa: E402
 
 from repro.core import LandmarkSpec, RatingMatrix  # noqa: E402
 from repro.core.landmark_cf import fit  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.lifecycle import buckets  # noqa: E402
 from repro.serving import (  # noqa: E402
     EngineConfig,
@@ -279,7 +280,7 @@ needs_mesh = pytest.mark.skipif(jax.device_count() < 8,
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((2, 4), ("pod", "data"))
+    return make_mesh((2, 4), ("pod", "data"))
 
 
 @needs_mesh

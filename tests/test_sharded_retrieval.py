@@ -18,6 +18,7 @@ import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
 from repro.core.graph import finalize_topk  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.retrieval.index import (  # noqa: E402
     IVFSpec, append, build_index, ensure_index_capacity, recall_at_k, search,
     search_early_exit)
@@ -34,7 +35,7 @@ AXES = ("pod", "data")
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((2, 4), AXES)
+    return make_mesh((2, 4), AXES)
 
 
 def _mk(u=300, n=16, seed=0, measure="cosine", payload_dtype="f32"):

@@ -18,6 +18,7 @@ import numpy as np  # noqa: E402
 
 from repro.core import LandmarkSpec, RatingMatrix, knn  # noqa: E402
 from repro.core.landmark_cf import fit, fit_distributed, fold_in  # noqa: E402
+from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.lifecycle import buckets  # noqa: E402
 from repro.lifecycle.refresh import RefreshManager  # noqa: E402
 from repro.train.checkpoint import (  # noqa: E402
@@ -33,7 +34,7 @@ SPEC = LandmarkSpec(n_landmarks=8, selection="popularity", k_neighbors=5)
 
 @pytest.fixture(scope="module")
 def mesh():
-    return jax.make_mesh((2, 4), ("pod", "data"))
+    return make_mesh((2, 4), ("pod", "data"))
 
 
 def _ratings(u, p, density=0.35, seed=0):
@@ -242,7 +243,7 @@ def test_distributed_refresh_oracle_exact_and_sharded_on_disk(mesh, tmp_path):
     assert loaded.representation.sharding.spec[0] == ("pod", "data")
     np.testing.assert_array_equal(np.asarray(loaded.graph.weights),
                                   np.asarray(oracle.graph.weights))
-    small = jax.sharding.Mesh(np.asarray(jax.devices()[:2]).reshape(2), ("data",))
+    small = make_mesh((2,), ("data",), devices=jax.devices()[:2])
     loaded2 = load_landmark_state(str(tmp_path), mesh=small)
     np.testing.assert_array_equal(np.asarray(loaded2.ratings), acc)
 

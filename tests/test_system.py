@@ -26,7 +26,6 @@ def test_paper_pipeline_flops_linear_in_landmarks():
                                dense_sims=True).sims
         ).lower(jax.random.PRNGKey(0), m.ratings)
         cost = lowered.compile().cost_analysis()
-        cost = cost[0] if isinstance(cost, (list, tuple)) else cost
         flops.append(cost["flops"])
     ratio = flops[2] / flops[0]
     assert 3.0 < ratio < 16.0, (flops, ratio)
