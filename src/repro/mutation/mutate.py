@@ -400,23 +400,39 @@ def repair(
 def drain_repairs(mst: MutableState, spec: LandmarkSpec, bq: int = 64,
                   *, chunk: int = 4096, ivf_index=None,
                   nprobe: Optional[int] = None) -> MutableState:
-    """Host driver: run :func:`repair` until the dirty bitmap is empty.
+    """Host driver: run :func:`repair` until the dirty bitmap is empty
+    (:func:`drain`)."""
+    return drain(mst, lambda m: repair(m, bq, spec, chunk=chunk,
+                                       ivf_index=ivf_index,
+                                       nprobe=nprobe)[0])
 
-    When an :mod:`repro.obs` instance is installed, the whole drain is one
-    ``repair.drain`` span and the repaired-row totals land on the
-    ``mutation.*`` counters — the write lane has no parameter path from
-    the serve loop, so this goes through the global hook."""
+
+def drain(mst, repair_once):
+    """Apply ``repair_once`` until ``mst.dirty_count()`` is 0; shared by
+    the single-device and the sharded drain.
+
+    Under the current :mod:`repro.obs` instance (the engine's, scoped to
+    its write lane, or the installed one) the drain is one ``repair.drain``
+    span holding one ``repair.round`` span per call, each with the rows it
+    fixed, and the totals land on the ``mutation.repaired_rows`` and
+    ``mutation.repair_rounds`` counters. Each round ends on the host's
+    read of the dirty count, which waits for the round on the device."""
     from repro import obs as obslib
 
-    n0 = int(mst.dirty_count())
+    left = n0 = int(mst.dirty_count())
+    rounds = 0
     with obslib.span("repair.drain", cat="mutation", args={"rows": n0}):
-        while mst.dirty_count() > 0:
-            mst, _ = repair(mst, bq, spec, chunk=chunk, ivf_index=ivf_index,
-                            nprobe=nprobe)
+        while left > 0:
+            args = {}
+            with obslib.span("repair.round", cat="mutation", args=args):
+                mst = repair_once(mst)
+                before, left = left, int(mst.dirty_count())
+                args["rows"] = before - left
+            rounds += 1
     o = obslib.current()
     if o is not None and o.enabled and n0:
-        o.registry.counter("mutation.repair_drains").inc()
         o.registry.counter("mutation.repaired_rows").inc(n0)
+        o.registry.counter("mutation.repair_rounds").inc(rounds)
     return mst
 
 

@@ -50,6 +50,7 @@ from repro.core.landmark_cf import (LandmarkState, ShardedLandmarkState,
 from repro.core.similarity import dense_similarity, masked_similarity
 from repro.core.types import LandmarkSpec, NeighborGraph
 from repro.lifecycle import buckets
+from repro.mutation.mutate import drain
 
 
 @jax.tree_util.register_pytree_node_class
@@ -335,21 +336,10 @@ def repair_sharded(
 
 def drain_repairs_sharded(msst: MutableStateSharded, spec: LandmarkSpec,
                           bq: int = 64) -> MutableStateSharded:
-    """Host driver: run :func:`repair_sharded` until no dirty rows remain.
-
-    Emits the same ``repair.drain`` span / ``mutation.*`` counters as the
-    single-device drain when an obs instance is installed."""
-    from repro import obs as obslib
-
-    n0 = int(msst.dirty_count())
-    with obslib.span("repair.drain", cat="mutation", args={"rows": n0}):
-        while msst.dirty_count() > 0:
-            msst, _ = repair_sharded(msst, bq, spec.d2)
-    o = obslib.current()
-    if o is not None and o.enabled and n0:
-        o.registry.counter("mutation.repair_drains").inc()
-        o.registry.counter("mutation.repaired_rows").inc(n0)
-    return msst
+    """Host driver: run :func:`repair_sharded` until no dirty rows remain,
+    with the single-device drain's spans and counters
+    (:func:`repro.mutation.mutate.drain`)."""
+    return drain(msst, lambda m: repair_sharded(m, bq, spec.d2)[0])
 
 
 # ------------------------------------------------------------------ lifecycle

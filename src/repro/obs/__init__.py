@@ -3,11 +3,13 @@
 One container object (:class:`Observability`) bundles the three
 substrates — a :class:`~repro.obs.registry.MetricsRegistry`, a
 :class:`~repro.obs.trace.Tracer`, and the profiling hooks — and is either
-threaded explicitly (``RequestEngine(..., obs=o)``) or installed as the
-process-wide current instance (:func:`install`) so deep subsystems that
-have no parameter path to the serve loop (mutation repair drains, the
-background refresh thread) can emit spans and counters via
-:func:`current` / :func:`span`.
+threaded explicitly (``RequestEngine(..., obs=o)``) or made current so
+deep subsystems that have no parameter path to the serve loop (mutation
+repair drains, the background refresh thread) can emit spans and counters
+via :func:`current` / :func:`span`. Current is, first, the instance a
+caller scoped to its own thread (:func:`scoped`: the engine's write lane
+does so around each traced write) and, failing that, the process-wide
+one of :func:`install` (the refresh thread).
 
 The disabled configuration costs nothing on hot paths: producers guard on
 ``tracer.active`` (one attribute read) and the engine's own bounded
@@ -24,6 +26,7 @@ per-executable launch/compile accounting.
 from __future__ import annotations
 
 import contextlib
+import contextvars
 import json
 import math
 import os
@@ -46,7 +49,7 @@ from repro.obs.profile import (
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "Sampler",
     "Tracer", "Observability", "DISABLED", "install", "uninstall",
-    "current", "span", "count_launch", "profile_trace",
+    "current", "scoped", "span", "count_launch", "profile_trace",
     "publish_compile_counts",
 ]
 
@@ -88,6 +91,9 @@ def _sanitize(x):
 DISABLED = Observability(enabled=False)
 
 _current: Optional[Observability] = None
+# per thread (each thread starts from an empty context): what scoped() set
+_scoped: contextvars.ContextVar = contextvars.ContextVar("repro_obs",
+                                                         default=None)
 
 
 def install(obs: Observability) -> None:
@@ -103,23 +109,37 @@ def uninstall() -> None:
 
 
 def current() -> Optional[Observability]:
-    return _current
+    """The instance scoped to this thread, else the installed one."""
+    o = _scoped.get()
+    return _current if o is None else o
+
+
+@contextlib.contextmanager
+def scoped(obs: Optional[Observability]):
+    """Make ``obs`` current for the calling thread for the block, without
+    touching the process-wide instance of :func:`install`."""
+    token = _scoped.set(obs)
+    try:
+        yield obs
+    finally:
+        _scoped.reset(token)
 
 
 @contextlib.contextmanager
 def span(name: str, cat: str = "bg", args: Optional[dict] = None,
          obs: Optional[Observability] = None):
-    """Record the block as one span on ``obs`` (default: the installed
-    current instance). No-op when nothing is installed or tracing is off —
-    background subsystems wrap coarse regions (a repair drain, a refit)
-    so the disabled cost is one generator frame per region, never
-    per-request."""
-    o = _current if obs is None else obs
+    """Record the block as one phase span on ``obs`` (default:
+    :func:`current`), a ``repro/<name>`` profiler annotation while it runs.
+    ``args`` is read when the block ends, so the block may fill it in.
+    No-op when nothing is current or tracing is off — subsystems wrap
+    coarse regions (a write's phases, a repair drain, a refit), so the
+    disabled cost is one generator frame per region, never per-request."""
+    o = current() if obs is None else obs
     if o is None or not o.tracer.active:
         yield None
         return
-    t0 = time.monotonic()
+    ph = o.tracer.phase(name, cat, time.monotonic, args)
     try:
         yield o
     finally:
-        o.tracer.complete(name, cat, t0, time.monotonic(), args=args)
+        o.tracer.complete_many([ph.end()])

@@ -11,14 +11,26 @@ and a small args dict. The engine emits:
                  ``args["parent"]`` — Chrome's flame view nests by
                  thread/time, the invariant tests check the ids.
   cat="engine"   per executed batch (regardless of sampling): an
-                 ``execute[kind]`` span on the read thread and an
-                 ``exec_wait`` span when the launch had to wait on
-                 ``exec_lock`` — the contention the sharded backend's
-                 serialized folds create is directly visible.
+                 ``execute[kind]`` span on the read thread and the
+                 ``exec_wait`` span before it, the wait on ``exec_lock``
+                 — the contention the sharded backend's serialized folds
+                 create is directly visible.
   cat="write"    per drained write: ``apply[fold|update|remove]``
                  including the atomic generation publish at its tail.
   cat="lifecycle"/"mutation"
                  background refresh fit/commit, repair drains, compaction.
+
+Phases. The read thread and the write lane also record *phases*: spans
+that tile what the thread does (``read.idle``, ``read.form``,
+``execute.dispatch``, ``write.mutate``, ``repair.round``, ...; the list is
+in docs/observability.md). A phase is opened with :meth:`Tracer.phase`,
+which enters a ``jax.profiler.TraceAnnotation`` named ``repro/<name>``
+while the phase runs, so any profiler capture holds the program's spans
+on the device trace's clock; :meth:`Phase.end` leaves the annotation and
+returns the span record for ``complete_many``. Every span the program
+records at the moment it runs goes through a phase; only the per-request
+spans above, assembled afterwards from timestamps taken on several
+threads, have no annotation.
 
 Sampling is a deterministic 64-bit LCG (same seed + rate ⇒ same accept
 sequence — replayable traces, testable sampler). The event buffer is
@@ -38,7 +50,11 @@ from __future__ import annotations
 import itertools
 import json
 import threading
-from typing import Dict, List, Optional
+from typing import Callable, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
+
+ANNOTATION = "repro/"  # prefix of the program's profiler annotations
 
 _LCG_MUL = 6364136223846793005
 _LCG_ADD = 1442695040888963407
@@ -63,6 +79,36 @@ class Sampler:
         self._state = (self._state * _LCG_MUL + _LCG_ADD) & _MASK64
         # top 53 bits → uniform in [0, 1)
         return (self._state >> 11) / float(1 << 53) < self.rate
+
+
+class Phase:
+    """One open phase of the calling thread. Construction enters the
+    ``repro/<name>`` profiler annotation and then reads ``clock`` for
+    ``t0``; :meth:`end`, on the same thread, reads ``t1`` and then leaves
+    the annotation. Each read sits next to its annotation call, so the
+    record and the annotation span the same interval to within a few µs,
+    whatever other threads do. ``args`` may be filled in before
+    :meth:`end`."""
+
+    __slots__ = ("name", "cat", "args", "t0", "t1", "_clock", "_ann")
+
+    def __init__(self, name: str, cat: str, clock: Callable[[], float],
+                 args: Optional[dict] = None) -> None:
+        self.name, self.cat, self.args = name, cat, args
+        self._clock = clock
+        self._ann = TraceAnnotation(ANNOTATION + name)
+        self._ann.__enter__()
+        self.t0 = clock()
+
+    def end(self) -> dict:
+        """Close the phase; its span record."""
+        self.t1 = self._clock()
+        self._ann.__exit__(None, None, None)
+        ev = {"name": self.name, "cat": self.cat, "t0": self.t0,
+              "t1": self.t1}
+        if self.args:
+            ev["args"] = self.args
+        return ev
 
 
 class Tracer:
@@ -99,6 +145,13 @@ class Tracer:
         return next(self._ids)
 
     # ----------------------------------------------------------- recording
+    def phase(self, name: str, cat: str, clock: Callable[[], float],
+              args: Optional[dict] = None) -> Phase:
+        """Open a phase of the calling thread, timed by ``clock`` (see
+        :class:`Phase`). Producers call it only behind a ``tracer.active``
+        guard."""
+        return Phase(name, cat, clock, args)
+
     def complete(self, name: str, cat: str, t0: float, t1: float, *,
                  tid: Optional[int] = None, span_id: Optional[int] = None,
                  parent: Optional[int] = None,

@@ -271,6 +271,22 @@ def _mutation_shape(m: int, lo: int = 8) -> int:
     return s
 
 
+def _pad_mutation(row_ids: np.ndarray, rows: Optional[np.ndarray]):
+    """Pad a mutation of the state rows ``row_ids`` to its power-of-two
+    shape (-1 pads) and move it to the device: ``(row ids, rating rows or
+    None, count)``."""
+    m = len(row_ids)
+    shape = _mutation_shape(m)
+    pid = np.full(shape, -1, np.int64)
+    pid[:m] = row_ids
+    if rows is None:
+        return jnp.asarray(pid, jnp.int32), None, jnp.int32(m)
+    prows = np.zeros((shape, rows.shape[1]), np.float32)
+    prows[:m] = rows
+    return (jnp.asarray(pid, jnp.int32),
+            jnp.asarray(prows, jnp.float32), jnp.int32(m))
+
+
 class MutableLocalBackend(LocalBackend):
     """:class:`LocalBackend` with the write path open.
 
@@ -314,46 +330,50 @@ class MutableLocalBackend(LocalBackend):
 
     def fold_in(self, rows: np.ndarray, bq: int) -> int:
         mst, gen = self._pub
-        new = self._mut.fold_in_rows(mst, jnp.asarray(rows), bq, self.spec,
-                                     min_bucket=self.min_bucket,
-                                     growth=self.growth)
-        jax.block_until_ready(new.bstate.state.ratings)
+        with obslib.span("write.prepare", cat="write"):
+            rows = jnp.asarray(rows)
+        with obslib.span("write.mutate", cat="write"):
+            new = self._mut.fold_in_rows(mst, rows, bq, self.spec,
+                                         min_bucket=self.min_bucket,
+                                         growth=self.growth)
+            jax.block_until_ready(new.bstate.state.ratings)
         if new.capacity not in self.caps_used:
             self._warm((new, gen + 1))
             self.caps_used.add(new.capacity)
-        self._pub = (new, gen + 1)
+        with obslib.span("write.publish", cat="write"):
+            self._pub = (new, gen + 1)
         return gen + 1
 
     def _pad_mutation(self, ids: np.ndarray, rows: Optional[np.ndarray]):
-        m = len(ids)
-        shape = _mutation_shape(m)
-        pid = np.full(shape, -1, np.int64)
-        pid[:m] = ids
-        if rows is None:
-            return jnp.asarray(pid, jnp.int32), None, jnp.int32(m)
-        prows = np.zeros((shape, rows.shape[1]), np.float32)
-        prows[:m] = rows
-        return (jnp.asarray(pid, jnp.int32),
-                jnp.asarray(prows, jnp.float32), jnp.int32(m))
+        with obslib.span("write.prepare", cat="write"):
+            return _pad_mutation(ids, rows)
 
-    def _publish_mutation(self, mst) -> int:
-        _, gen = self._pub
-        self.repaired_rows += mst.dirty_count()
+    def _publish_mutation(self, mutate) -> int:
+        """Apply ``mutate`` to the live state, drain its repairs, publish:
+        the ``write.mutate``, ``repair.drain`` and ``write.publish``
+        phases."""
+        mst, gen = self._pub
+        with obslib.span("write.mutate", cat="write"):
+            mst = mutate(mst)
+            jax.block_until_ready(mst.bstate.state.ratings)
+            self.repaired_rows += mst.dirty_count()
         mst = self._mut.drain_repairs(mst, self.spec, self.repair_bq)
-        jax.block_until_ready(mst.bstate.state.ratings)
-        self._pub = (mst, gen + 1)
+        with obslib.span("write.publish", cat="write"):
+            jax.block_until_ready(mst.bstate.state.ratings)
+            self._pub = (mst, gen + 1)
         return gen + 1
 
     def apply_update(self, ids: np.ndarray, rows: np.ndarray) -> int:
         pid, prows, m = self._pad_mutation(np.asarray(ids),
                                            np.asarray(rows))
         return self._publish_mutation(
-            self._mut.update_ratings(self._pub[0], pid, prows, m, self.spec))
+            lambda mst: self._mut.update_ratings(mst, pid, prows, m,
+                                                 self.spec))
 
     def apply_remove(self, ids: np.ndarray) -> int:
         pid, _, m = self._pad_mutation(np.asarray(ids), None)
         return self._publish_mutation(
-            self._mut.remove_users(self._pub[0], pid, m))
+            lambda mst: self._mut.remove_users(mst, pid, m))
 
     def refresh(self) -> Tuple[int, np.ndarray]:
         """Refresh-boundary compaction: drain outstanding repairs, slide the
@@ -426,10 +446,13 @@ class MutableShardedBackend(ShardedBackend):
 
     def fold_in(self, rows: np.ndarray, bq: int) -> int:
         msst, id_shard, id_slot, gen = self._pub
-        new, shards, slots = self._mut.fold_in_rows_sharded(
-            msst, jnp.asarray(rows), bq, self.spec,
-            min_bucket=self.min_bucket, growth=self.growth)
-        jax.block_until_ready(new.sstate.state.ratings)
+        with obslib.span("write.prepare", cat="write"):
+            rows = jnp.asarray(rows)
+        with obslib.span("write.mutate", cat="write"):
+            new, shards, slots = self._mut.fold_in_rows_sharded(
+                msst, rows, bq, self.spec,
+                min_bucket=self.min_bucket, growth=self.growth)
+            jax.block_until_ready(new.sstate.state.ratings)
         pub = (new,
                np.concatenate([id_shard, np.asarray(shards)]),
                np.concatenate([id_slot, np.asarray(slots)]),
@@ -437,43 +460,41 @@ class MutableShardedBackend(ShardedBackend):
         if new.capacity not in self.caps_used:
             self._warm(pub)
             self.caps_used.add(new.capacity)
-        self._pub = pub
+        with obslib.span("write.publish", cat="write"):
+            self._pub = pub
         return gen + 1
 
-    def _publish_mutation(self, msst) -> int:
-        _, id_shard, id_slot, gen = self._pub
-        self.repaired_rows += msst.dirty_count()
+    def _publish_mutation(self, mutate) -> int:
+        """Sharded twin of ``MutableLocalBackend._publish_mutation``, with
+        the same phases."""
+        msst, id_shard, id_slot, gen = self._pub
+        with obslib.span("write.mutate", cat="write"):
+            msst = mutate(msst)
+            jax.block_until_ready(msst.sstate.state.ratings)
+            self.repaired_rows += msst.dirty_count()
         msst = self._mut.drain_repairs_sharded(msst, self.spec,
                                                self.repair_bq)
-        jax.block_until_ready(msst.sstate.state.ratings)
-        self._pub = (msst, id_shard, id_slot, gen + 1)
+        with obslib.span("write.publish", cat="write"):
+            jax.block_until_ready(msst.sstate.state.ratings)
+            self._pub = (msst, id_shard, id_slot, gen + 1)
         return gen + 1
 
     def _mutation_batch(self, ids: np.ndarray, rows: Optional[np.ndarray]):
-        pub = self._pub
-        m = len(ids)
-        shape = _mutation_shape(m)
-        sids = np.asarray(self._sharded_ids(pub, np.asarray(ids)), np.int64)
-        pid = np.full(shape, -1, np.int64)
-        pid[:m] = sids
-        if rows is None:
-            return jnp.asarray(pid, jnp.int32), None, jnp.int32(m)
-        prows = np.zeros((shape, rows.shape[1]), np.float32)
-        prows[:m] = rows
-        return (jnp.asarray(pid, jnp.int32),
-                jnp.asarray(prows, jnp.float32), jnp.int32(m))
+        with obslib.span("write.prepare", cat="write"):
+            sids = np.asarray(self._sharded_ids(self._pub, ids), np.int64)
+            return _pad_mutation(sids, rows)
 
     def apply_update(self, ids: np.ndarray, rows: np.ndarray) -> int:
         pid, prows, m = self._mutation_batch(np.asarray(ids),
                                              np.asarray(rows))
         return self._publish_mutation(
-            self._mut.update_ratings_sharded(self._pub[0], pid, prows, m,
-                                             self.spec))
+            lambda msst: self._mut.update_ratings_sharded(msst, pid, prows,
+                                                          m, self.spec))
 
     def apply_remove(self, ids: np.ndarray) -> int:
         pid, _, m = self._mutation_batch(np.asarray(ids), None)
         return self._publish_mutation(
-            self._mut.remove_users_sharded(self._pub[0], pid, m))
+            lambda msst: self._mut.remove_users_sharded(msst, pid, m))
 
     def refresh(self) -> Tuple[int, np.ndarray]:
         """Per-shard compaction at the swap boundary. Returns
@@ -647,7 +668,12 @@ class RequestEngine:
             heapq.heappush(self._heap, entry)
         return batch
 
-    def _execute(self, batch: List[Request]) -> None:
+    def _execute(self, batch: List[Request], form=None) -> None:
+        """Run one batch. ``form`` is the open ``read.form`` phase when
+        tracing: the batch's phases then tile the read thread's time from
+        pickup to the last ``done.set()``, and ``execute[kind]`` (launch to
+        answers on the host) holds ``execute.dispatch``, ``.device`` and
+        ``.fetch``."""
         kind = batch[0].kind
         rows = sum(r.n_rows for r in batch)
         shape = self.config.pad_shape(rows)
@@ -660,22 +686,44 @@ class RequestEngine:
                 items[off:off + r.n_rows] = r.items
             off += r.n_rows
         tr = self._tracer
-        t_ready = self.clock() if tr.active else 0.0
+        traced = form is not None
+        if traced:
+            evs = [form.end()]
+            ph = tr.phase("exec_wait", "engine", self.clock, {"kind": kind})
         with self.exec_lock:
-            t_launch = self.clock() if tr.active else 0.0
+            if traced:
+                evs.append(ph.end())
+                ex = tr.phase(f"execute[{kind}]", "engine", self.clock,
+                              {"rows": rows, "shape": shape})
+                ph = tr.phase("execute.dispatch", "engine", self.clock)
             pub = self.backend.snapshot()
             if kind == "pair":
-                out = np.asarray(
-                    jax.block_until_ready(
-                        self.backend.predict_pairs(pub, users, items)))
+                out = self.backend.predict_pairs(pub, users, items)
+            else:
+                out = self.backend.recommend_topn(pub, users,
+                                                  self.config.topn)
+            if traced:
+                evs.append(ph.end())
+                ph = tr.phase("execute.device", "engine", self.clock)
+            jax.block_until_ready(out)
+            if traced:
+                evs.append(ph.end())
+                ph = tr.phase("execute.fetch", "engine", self.clock)
+            if kind == "pair":
+                out = np.asarray(out)
                 self.nonfinite += int((~np.isfinite(out[:rows])).sum())
             else:
-                ti, ts = self.backend.recommend_topn(pub, users,
-                                                     self.config.topn)
-                out = (np.asarray(jax.block_until_ready(ti)),
-                       np.asarray(jax.block_until_ready(ts)))
-        now = self.clock()
+                out = (np.asarray(out[0]), np.asarray(out[1]))
         gen = pub[-1]   # both backends publish (..., generation)
+        if traced:
+            bid = batch[0].seq
+            evs.append(ph.end())
+            ex.args.update(gen=gen, batch=bid)
+            evs.append(ex.end())
+            now = ex.t1
+            ph = tr.phase("read.scatter", "engine", self.clock)
+        else:
+            now = self.clock()
         off = 0
         for r in batch:
             if kind == "pair":
@@ -696,17 +744,8 @@ class RequestEngine:
         self.pad_rows += shape - rows
         key = (kind, shape)
         self.launches[key] = self.launches.get(key, 0) + 1
-        if tr.active:
-            bid = batch[0].seq
-            evs = []
-            if t_launch > t_ready:
-                evs.append({"name": "exec_wait", "cat": "engine",
-                            "t0": t_ready, "t1": t_launch,
-                            "args": {"kind": kind}})
-            evs.append({"name": f"execute[{kind}]", "cat": "engine",
-                        "t0": t_launch, "t1": now,
-                        "args": {"rows": rows, "shape": shape, "gen": gen,
-                                 "batch": bid}})
+        if traced:
+            evs.append(ph.end())
             tr.complete_many(evs)
             recs = [(kind, r.t_submit, r.t_pickup, now, r.trace_id,
                      r.n_rows, gen, bid) for r in batch if r.sampled]
@@ -716,15 +755,20 @@ class RequestEngine:
     def pump_reads(self, max_batches: Optional[int] = None) -> int:
         """Drain queued reads now; returns the number of batches executed."""
         n = 0
+        tr = self._tracer
         while max_batches is None or n < max_batches:
+            form = None
             with self._lock:
+                if tr.active and self._heap:
+                    form = tr.phase("read.form", "engine", self.clock,
+                                    {"queued": self._queued_rows})
                 batch = self._form_batch()
             if not batch:
                 break
             tp = self.clock()
             for r in batch:
                 r.t_pickup = tp
-            self._execute(batch)
+            self._execute(batch, form)
             n += 1
         return n
 
@@ -738,24 +782,28 @@ class RequestEngine:
 
     def pump_folds(self, max_folds: Optional[int] = None) -> int:
         """Drain queued writes — fold-ins, updates, removals — now (never
-        called from the read path)."""
+        called from the read path). With tracing on, each write runs with
+        the engine's obs current on this thread (``obslib.scoped``), so the
+        backend's ``write.*`` and ``repair.*`` phases record here without
+        a process-wide ``install()``."""
         n = 0
         tr = self._tracer
+        serialize = getattr(self.backend, "serialize_folds", False)
         while max_folds is None or n < max_folds:
             with self._lock:
                 if not self._folds:
                     break
                 req = self._folds.pop(0)
-            t_pickup = self.clock() if tr.active else 0.0
-            req.t_pickup = t_pickup
-            if getattr(self.backend, "serialize_folds", False):
-                with self.exec_lock:
-                    t_apply = self.clock() if tr.active else t_pickup
-                    gen = self._apply_write(req)
+            if tr.active:
+                gen, now, evs = self._apply_traced(req, serialize)
             else:
-                t_apply = t_pickup
-                gen = self._apply_write(req)
-            now = self.clock()
+                if serialize:
+                    with self.exec_lock:
+                        gen = self._apply_write(req)
+                else:
+                    gen = self._apply_write(req)
+                now = self.clock()
+                evs = None
             req.result = gen
             req.generation = gen
             req.t_done = now
@@ -768,41 +816,77 @@ class RequestEngine:
                     self.mutated_rows += len(req.users)
                 self._verify_ring.clear()   # prior generation retired
             req.done.set()
-            if tr.active:
-                if t_apply > t_pickup:
-                    tr.complete("exec_wait", "engine", t_pickup, t_apply,
-                                args={"kind": req.kind})
-                tr.complete(f"apply[{req.kind}]", "write", t_apply, now,
-                            args={"rows": req.n_rows, "gen": gen})
+            if evs is not None:
+                tr.complete_many(evs)
                 if req.sampled:
                     tr.complete_requests(
-                        [(req.kind, req.t_submit, t_pickup, now,
+                        [(req.kind, req.t_submit, req.t_pickup, now,
                           req.trace_id, req.n_rows, gen, None)],
                         child="apply")
             n += 1
         return n
+
+    def _apply_traced(self, req: Request, serialize: bool):
+        """One write as phases: ``exec_wait`` (only where folds serialize
+        with reads) then ``apply[kind]``, pickup to publish. Returns
+        ``(generation, t_done, span records)``."""
+        tr = self._tracer
+        req.t_pickup = self.clock()
+        evs = []
+        with obslib.scoped(self.obs):
+            if serialize:
+                ph = tr.phase("exec_wait", "engine", self.clock,
+                              {"kind": req.kind})
+                self.exec_lock.acquire()
+                evs.append(ph.end())
+            try:
+                ph = tr.phase(f"apply[{req.kind}]", "write", self.clock,
+                              {"rows": req.n_rows})
+                gen = self._apply_write(req)
+                ph.args["gen"] = gen
+                evs.append(ph.end())
+            finally:
+                if serialize:
+                    self.exec_lock.release()
+        now = ph.t1
+        return gen, now, evs
 
     # -------------------------------------------------------------- threaded
     def start(self) -> None:
         self._running = True
 
         def read_loop():
+            tr = self._tracer
             while True:
+                traced = tr.active
+                evs = []
                 with self._lock:
+                    ph = (tr.phase("read.idle", "engine", self.clock)
+                          if traced and self._running and not self._heap
+                          else None)
                     while self._running and not self._heap:
                         self._read_cond.wait(timeout=0.05)
-                    if not self._running and not self._heap:
-                        return
+                    if ph is not None:
+                        evs.append(ph.end())
                     first = self._heap[0][2] if self._heap else None
+                if first is None:   # stopped with nothing queued
+                    if evs:
+                        tr.complete_many(evs)
+                    return
                 # brief fill wait: let the batch accumulate, bounded by
                 # max_wait and by the earliest deadline
-                if first is not None:
-                    wait = min(self.config.max_wait_ms / 1e3,
-                               max(0.0, first.deadline - self.clock()))
-                    deadline = self.clock() + wait
-                    while (self.clock() < deadline
-                           and self._queued_rows < self.config.max_batch):
-                        time.sleep(0.0005)
+                ph = None
+                if traced:
+                    ph = tr.phase("read.fill", "engine", self.clock)
+                t = self.clock()
+                deadline = t + min(self.config.max_wait_ms / 1e3,
+                                   max(0.0, first.deadline - t))
+                while (self.clock() < deadline
+                       and self._queued_rows < self.config.max_batch):
+                    time.sleep(0.0005)
+                if ph is not None:
+                    evs.append(ph.end())
+                    tr.complete_many(evs)
                 self.pump_reads(max_batches=1)
 
         def fold_loop():

@@ -1,16 +1,21 @@
 """Unified observability layer: histogram bucket-boundary exactness and
 merge algebra, registry publish/delta/export semantics, seeded-sampler
 determinism, span parent/ordering invariants under concurrent submit, the
-zero-overhead-when-disabled contract, and the per-kind shed counters +
-queue gauges the engine publishes.
+zero-overhead-when-disabled contract, the per-kind shed counters +
+queue gauges the engine publishes, and the read-thread and write-lane
+phases: that they tile the read thread's time, nest as documented, record
+under the engine's obs without ``install()``, and sit in a
+``jax.profiler`` capture as ``repro/`` annotations of the same durations.
 
 The engine-backed tests reuse the test_serving_engine.py fixture shape
 (tiny fitted state, LocalBackend) — single-device, runs anywhere.
 """
+import glob
 import json
 import math
 import os
 import threading
+import time
 
 import pytest
 
@@ -38,7 +43,12 @@ from repro.obs import (  # noqa: E402
     Sampler,
     Tracer,
 )
-from repro.serving import EngineConfig, LocalBackend, RequestEngine  # noqa: E402
+from repro.serving import (  # noqa: E402
+    EngineConfig,
+    LocalBackend,
+    MutableLocalBackend,
+    RequestEngine,
+)
 
 SPEC = LandmarkSpec(n_landmarks=8, selection="popularity", k_neighbors=5)
 U, P = 64, 24
@@ -319,7 +329,8 @@ def test_zero_overhead_when_disabled(state):
         raise AssertionError("disabled tracer was invoked on the hot path")
 
     saved = {m: getattr(tr, m) for m in
-             ("complete", "complete_many", "should_sample", "new_id")}
+             ("complete", "complete_many", "should_sample", "new_id",
+              "phase")}
     for m in saved:
         setattr(tr, m, boom)
     try:
@@ -451,3 +462,183 @@ def test_exports_satisfy_ci_schema_checker(state, tmp_path):
                parse_constant=lambda s: pytest.fail(f"non-strict {s}"))
     names = {e["name"] for e in doc["traceEvents"] if e.get("ph") == "X"}
     assert "execute[pair]" in names and "apply[fold]" in names
+
+
+# ------------------------------------- phases, on the profiler's clock too
+
+READ_PHASES = ("read.idle", "read.fill", "read.form", "exec_wait",
+               "execute.dispatch", "execute.device", "execute.fetch",
+               "read.scatter")
+BATCH_PHASES = ("read.form", "execute.dispatch", "execute.device",
+                "execute.fetch", "read.scatter")
+WRITE_PHASES = ("write.prepare", "write.mutate", "repair.drain",
+                "repair.round", "write.publish")
+PHASE_CFG = EngineConfig(max_batch=16, min_shape=4, queue_cap=4096,
+                         max_wait_ms=0.5, slo_ms=500.0, fold_bq=8, topn=5)
+
+
+def _mutable_backend(state):
+    return MutableLocalBackend(buckets.from_state(state, min_bucket=U), SPEC,
+                               min_bucket=U)
+
+
+def _most_cited(state) -> int:
+    """The user most neighbour lists cite: updating it dirties rows."""
+    idx = np.asarray(state.graph.indices)
+    return int(np.bincount(idx[idx >= 0].ravel(), minlength=U).argmax())
+
+
+def _drive(eng, seconds, rng, update=None):
+    """Reads from this thread with pauses for ``seconds`` (the read thread
+    idles between them), plus one ``update`` (users, rows) half-way."""
+    reqs = []
+    t_end = time.monotonic() + seconds
+    t_write = time.monotonic() + seconds / 2
+    while time.monotonic() < t_end:
+        m = int(rng.integers(1, 5))
+        users = rng.integers(0, U, m)
+        if rng.random() < 0.7:
+            reqs.append(eng.submit("pair", users=users,
+                                   items=rng.integers(0, P, m)))
+        else:
+            reqs.append(eng.submit("topn", users=users))
+        if update is not None and time.monotonic() > t_write:
+            reqs.append(eng.submit("update", users=update[0],
+                                   rows=update[1]))
+            update = None
+        time.sleep(0.004)
+    for r in reqs:
+        assert r is not None and r.done.wait(10.0)
+    return reqs
+
+
+def test_read_phases_tile_the_read_thread(state):
+    """The read thread's phases cover its whole life, back to back."""
+    o = Observability(sample_rate=0.0, seed=0)
+    eng = RequestEngine(_local_backend(state), PHASE_CFG, obs=o)
+    t0 = time.monotonic()
+    eng.start()
+    tid = eng._threads[0].ident
+    _drive(eng, 0.8, np.random.default_rng(8))
+    eng.stop()
+    life = time.monotonic() - t0
+    evs = sorted((e for e in o.tracer.events()
+                  if e["tid"] == tid and e["name"] in READ_PHASES),
+                 key=lambda e: e["t0"])
+    assert {e["name"] for e in evs} == set(READ_PHASES)
+    for a, b in zip(evs, evs[1:]):
+        assert a["t1"] <= b["t0"], (a, b)  # no two phases overlap
+    covered = sum(e["t1"] - e["t0"] for e in evs)
+    assert 0.95 * life <= covered <= life, (covered, life)
+    assert o.tracer.dropped == 0
+
+
+def test_every_batch_has_one_of_each_phase(state):
+    """Per batch: one of each phase, in order; ``execute[kind]`` (launch
+    to answers on the host) holds dispatch, device and fetch; ``read.form``
+    carries the queued rows at pickup."""
+    o = Observability(sample_rate=0.0, seed=0)
+    eng = RequestEngine(_local_backend(state), PHASE_CFG, obs=o)
+    rng = np.random.default_rng(9)
+    for i in range(40):
+        users = rng.integers(0, U, int(rng.integers(1, 5)))
+        if i % 3:
+            r = eng.submit("pair", users=users,
+                           items=rng.integers(0, P, len(users)))
+        else:
+            r = eng.submit("topn", users=users)
+        assert r is not None
+    queued = eng.stats()["queue_rows"]
+    n = eng.pump_reads()
+    assert n == eng.batches > 1
+    evs = o.tracer.events()
+
+    def of(name):
+        return sorted((e for e in evs if e["name"] == name),
+                      key=lambda e: e["t0"])
+
+    execs = sorted((e for e in evs if e["name"].startswith("execute[")),
+                   key=lambda e: e["t0"])
+    assert len(execs) == n
+    for name in BATCH_PHASES + ("exec_wait",):
+        assert len(of(name)) == n, name
+    for ex, form, wait, disp, dev, fetch, scat in zip(
+            execs, of("read.form"), of("exec_wait"),
+            of("execute.dispatch"), of("execute.device"),
+            of("execute.fetch"), of("read.scatter")):
+        times = [form["t0"], form["t1"], wait["t0"], wait["t1"], ex["t0"],
+                 disp["t0"], disp["t1"], dev["t0"], dev["t1"], fetch["t0"],
+                 fetch["t1"], ex["t1"], scat["t0"], scat["t1"]]
+        assert times == sorted(times)
+        assert set(ex["args"]) == {"rows", "shape", "gen", "batch"}
+        assert form["args"]["queued"] >= ex["args"]["rows"]
+    assert of("read.form")[0]["args"]["queued"] == queued
+
+
+def test_write_lane_phases_record_under_the_engine_obs(state):
+    """An update through the engine records its phases and the drain's
+    counters on the engine's obs, with nothing installed process-wide."""
+    o = Observability(sample_rate=1.0, seed=0)
+    eng = RequestEngine(_mutable_backend(state), PHASE_CFG, obs=o)
+    assert obslib.current() is None
+    r = eng.submit("update", users=[_most_cited(state)],
+                   rows=_ratings(1, P, seed=40))
+    assert r is not None and eng.pump_folds() == 1
+    assert obslib.current() is None
+    evs = o.tracer.events()
+    (apply,) = [e for e in evs if e["name"] == "apply[update]"]
+    got = {name: [e for e in evs if e["name"] == name]
+           for name in WRITE_PHASES}
+    for name in ("write.prepare", "write.mutate", "repair.drain",
+                 "write.publish"):
+        assert len(got[name]) == 1, name
+    (drain,) = got["repair.drain"]
+    rounds = got["repair.round"]
+    assert drain["args"]["rows"] > 0 and len(rounds) >= 1
+    assert sum(e["args"]["rows"] for e in rounds) == drain["args"]["rows"]
+    order = [got[name][0] for name in ("write.prepare", "write.mutate",
+                                       "repair.drain", "write.publish")]
+    for a, b in zip(order, order[1:]):
+        assert a["t1"] <= b["t0"]
+    for e in order + rounds:
+        assert apply["t0"] <= e["t0"] <= e["t1"] <= apply["t1"]
+    for e in rounds:
+        assert drain["t0"] <= e["t0"] <= e["t1"] <= drain["t1"]
+    counters = o.registry.snapshot()["counters"]
+    assert counters["mutation.repair_rounds"] == len(rounds)
+    assert counters["mutation.repaired_rows"] == drain["args"]["rows"]
+
+
+def test_profiler_capture_holds_each_phase(state, tmp_path):
+    """Each phase is a ``repro/`` annotation in a ``jax.profiler`` capture
+    (Python tracer off, as the chip benchmark captures), as long as the
+    tracer's record of it to within 50 us."""
+    o = Observability(sample_rate=0.0, seed=0)
+    eng = RequestEngine(_mutable_backend(state), PHASE_CFG, obs=o)
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        eng.start()
+        _drive(eng, 0.4, np.random.default_rng(10),
+               update=([_most_cited(state)], _ratings(1, P, seed=41)))
+        eng.stop()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    captured = {}
+    for plane in jax.profiler.ProfileData.from_file(path).planes:
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name.startswith("repro/"):
+                    captured.setdefault(ev.name[len("repro/"):], []).append(
+                        (ev.start_ns, ev.duration_ns * 1e-9))
+    recorded = {}
+    for e in o.tracer.events():
+        recorded.setdefault(e["name"], []).append((e["t0"],
+                                                   e["t1"] - e["t0"]))
+    for name in READ_PHASES + WRITE_PHASES + ("apply[update]",):
+        cap, rec = sorted(captured[name]), sorted(recorded[name])
+        assert len(cap) == len(rec), name
+        for (_, dc), (_, dr) in zip(cap, rec):
+            assert abs(dc - dr) < 50e-6, (name, dc, dr)
