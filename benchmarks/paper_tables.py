@@ -341,7 +341,7 @@ def refresh_vs_refit_bench(u0=1024, n_items=192, waves=6, arrivals=128,
                 return
             _, st = done
             snap_u = st.ratings.shape[0]
-            delta = np.asarray(bst.state.ratings)[snap_u:int(bst.n_valid)]
+            delta = bst.host_ratings(snap_u, int(bst.n_valid))
             bst = buckets.fold_in_rows(buckets.from_state(st, min_bucket=u0),
                                        delta, arrivals, spec, min_bucket=u0)
             caps.add(bst.capacity)
@@ -356,7 +356,7 @@ def refresh_vs_refit_bench(u0=1024, n_items=192, waves=6, arrivals=128,
                 jax.block_until_ready(buckets.predict_pairs(bst, users, items))
                 worst = max(worst, time.perf_counter() - t0)
             if wave == waves // 2:  # drift point: rebuild the artifact
-                acc = np.asarray(bst.state.ratings)[:int(bst.n_valid)]
+                acc = bst.host_ratings(0, int(bst.n_valid))
                 if variant == "background":
                     manager.request(acc, generation=1)
                 else:

@@ -22,6 +22,15 @@ The graph entry points accept an optional ``n_valid`` (traced scalar): rows
 ``>= n_valid`` are bucket padding (``repro.lifecycle.buckets``) and their
 weights are forced to 0 before Eq. (1), so a padded slot can never contribute
 to a prediction or a recommendation even if its graph row holds stale data.
+
+A row's mask, mean and centred values depend on that row alone, so the
+request-path entry points take them from the rows a batch gathers — its
+users' and their neighbours' — and never pass over the whole matrix:
+``recommend_topn_graph`` always, ``predict_pairs_graph`` whenever those
+``B·(k+1)`` rows are no more than the matrix holds (:func:`gathers_row_stats`,
+a static shape rule; a larger batch, such as the lifecycle monitor's holdout
+reservoir, reads less in one pass). Both paths apply :func:`_center` to the
+same f32 rows.
 """
 from __future__ import annotations
 
@@ -70,17 +79,26 @@ def _topk_neighbors(sim_row: jax.Array, self_idx: jax.Array, k: int):
 
 
 def _center(ratings: jax.Array):
-    """(mask, per-user means, mean-centered ratings) for Eq. (1)."""
+    """(mask, per-row means, mean-centered ratings) for Eq. (1) of any block
+    of rating rows (``(..., P)``): the whole matrix or gathered rows. Each
+    row's statistics depend on that row alone."""
     mask = (ratings != 0).astype(ratings.dtype)
-    cnt = mask.sum(axis=1)
-    means = jnp.where(cnt > 0, ratings.sum(axis=1) / jnp.maximum(cnt, 1.0), 0.0)
-    return mask, means, (ratings - means[:, None]) * mask
+    cnt = mask.sum(axis=-1)
+    means = jnp.where(cnt > 0, ratings.sum(axis=-1) / jnp.maximum(cnt, 1.0),
+                      0.0)
+    return mask, means, (ratings - means[..., None]) * mask
 
 
-def _block_predict(idx, w, centered, mask, mu):
-    """Eq. (1) for one user block given its (block, k) neighbor lists."""
-    nb_centered = centered[idx]  # gathers: (block, k, P)
-    nb_mask = mask[idx]
+def gathers_row_stats(rows: int, k: int, capacity: int) -> bool:
+    """Whether a pair batch of ``rows`` users takes Eq. (1)'s row means
+    from its ``rows·(k+1)`` gathered rows (True) or from one pass over the
+    ``capacity`` rows of the matrix (False): whichever reads fewer rows."""
+    return rows * (k + 1) <= capacity
+
+
+def _block_predict(w, nb_centered, nb_mask, mu):
+    """Eq. (1) for one user block given its gathered (block, k, P) neighbour
+    rows, centred and masked."""
     # HIGHEST: at DEFAULT precision the TPU feeds f32 operands to the MXU
     # as bf16, an error of ~1e-2 in a rating prediction
     hi = jax.lax.Precision.HIGHEST
@@ -111,7 +129,7 @@ def predict_all(
         ids = jax.lax.dynamic_slice_in_dim(user_ids, b * block, block)
         idx, w = jax.vmap(_topk_neighbors, in_axes=(0, 0, None))(rows, ids, k)
         mu = jax.lax.dynamic_slice_in_dim(means_p, b * block, block)
-        return _block_predict(idx, w, centered, mask, mu)
+        return _block_predict(w, centered[idx], mask[idx], mu)
 
     preds = jax.lax.map(one_block, jnp.arange(n_blocks))
     preds = preds.reshape(n_blocks * block, -1)[:n_users]
@@ -138,19 +156,20 @@ def predict_all_graph(
         idx = jax.lax.dynamic_slice_in_dim(idx_p, b * block, block, axis=0)
         w = jax.lax.dynamic_slice_in_dim(w_p, b * block, block, axis=0)
         mu = jax.lax.dynamic_slice_in_dim(means_p, b * block, block)
-        return _block_predict(idx, w, centered, mask, mu)
+        return _block_predict(w, centered[idx], mask[idx], mu)
 
     preds = jax.lax.map(one_block, jnp.arange(n_blocks))
     preds = preds.reshape(n_blocks * block, -1)[:n_users]
     return preds
 
 
-def _pair_predict(idx, w, u, v, ratings, mask, means):
-    r = ratings[idx, v]
-    m = mask[idx, v]
-    num = jnp.sum(w * (r - means[idx]) * m)
+def _pair_predict(w, r, mu_nb, mu_u):
+    """Eq. (1) for one pair from its k neighbours' ratings ``r`` of the
+    item, their means ``mu_nb`` and the user's mean ``mu_u``."""
+    m = (r != 0).astype(r.dtype)
+    num = jnp.sum(w * (r - mu_nb) * m)
     den = jnp.sum(jnp.abs(w) * m)
-    return means[u] + num / jnp.maximum(den, EPS)
+    return mu_u + num / jnp.maximum(den, EPS)
 
 
 @partial(jax.jit, static_argnames=("k",))
@@ -162,16 +181,16 @@ def predict_pairs(
     k: int = 13,
 ) -> jax.Array:
     """Predict only the requested (user, item) pairs — the test-fold path."""
-    mask, means, _ = _center(ratings)
+    _, means, _ = _center(ratings)
 
     def one(u, v):
         idx, w = _topk_neighbors(sims[u], u, k)
-        return _pair_predict(idx, w, u, v, ratings, mask, means)
+        return _pair_predict(w, ratings[idx, v], means[idx], means[u])
 
     return jax.vmap(one)(users, items)
 
 
-@partial(jax.jit, static_argnames=("n", "shard_cap"))
+@partial(jax.jit, static_argnames=("n", "shard_cap", "n_items"))
 def recommend_topn_graph(
     graph: NeighborGraph,
     ratings: jax.Array,  # (U, P), 0 == missing
@@ -181,6 +200,7 @@ def recommend_topn_graph(
     n_valid=None,  # () int32 (or (S,) with shard_cap): bucket-padding mask
     shard_cap=None,  # static per-shard capacity of a sharded graph
     tomb=None,  # (capacity,) bool: tombstoned rows never contribute
+    n_items=None,  # static: columns >= n_items are padding, never items
 ):
     """Top-N unseen items per query user — the serve-path recommendation op.
 
@@ -190,14 +210,18 @@ def recommend_topn_graph(
     degrades to arbitrary-but-finite rather than NaN. A user with fewer than
     ``n`` unrated items gets id -1 / score -inf in the exhausted slots — a
     rated item is never returned. ``n_valid`` zeroes padded-row neighbor
-    weights (see module docstring).
+    weights (see module docstring). ``n_items`` cuts the zero columns of a
+    lane-aligned matrix (``repro.lifecycle.buckets``) off the scored items.
+    The row statistics come from the gathered rows (module docstring).
     """
-    mask, means, centered = _center(ratings)
     idx = graph.indices[users]  # (B, k)
     w = _mask_padded_rows(idx, graph.weights[users], n_valid,
-                          shard_cap, tomb).astype(centered.dtype)
-    preds = _block_predict(idx, w, centered, mask, means[users])  # (B, P)
-    preds = jnp.where(mask[users] > 0, -jnp.inf, preds)  # never re-recommend
+                          shard_cap, tomb).astype(ratings.dtype)
+    nb_mask, _, nb_centered = _center(ratings[idx])  # (B, k, P)
+    u_mask, mu, _ = _center(ratings[users])  # (B, P)
+    preds = _block_predict(w, nb_centered, nb_mask, mu)[:, :n_items]
+    rated = u_mask[:, :n_items] > 0
+    preds = jnp.where(rated, -jnp.inf, preds)  # never re-recommend
     scores, items = jax.lax.top_k(preds, n)
     items = jnp.where(jnp.isfinite(scores), items, -1)
     return items, scores
@@ -218,12 +242,14 @@ def predict_pairs_graph(
 
     ``n_valid`` zeroes padded-row neighbor weights (see module docstring).
     """
-    mask, means, _ = _center(ratings)
     idx_b = graph.indices[users]  # (B, k)
     w_b = _mask_padded_rows(idx_b, graph.weights[users], n_valid, shard_cap,
                             tomb)
-
-    def one(idx, w, u, v):
-        return _pair_predict(idx, w, u, v, ratings, mask, means)
-
-    return jax.vmap(one)(idx_b, w_b, users, items)
+    if gathers_row_stats(users.shape[0], graph.k, ratings.shape[0]):
+        _, mu_nb, _ = _center(ratings[idx_b])  # means of (B, k, P) rows
+        _, mu_u, _ = _center(ratings[users])
+    else:
+        _, means, _ = _center(ratings)
+        mu_nb, mu_u = means[idx_b], means[users]
+    r_b = ratings[idx_b, items[:, None]]  # (B, k): each neighbour's rating
+    return jax.vmap(_pair_predict)(w_b, r_b, mu_nb, mu_u)

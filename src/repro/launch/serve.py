@@ -246,7 +246,7 @@ def _timed_requests(bst, rng, args):
     from repro.lifecycle import buckets
 
     u = int(bst.n_valid)
-    p = bst.state.ratings.shape[1]
+    p = bst.n_items
 
     def pair_batch():
         users = jnp.asarray(rng.integers(0, u, args.batch).astype(np.int32))
@@ -481,7 +481,7 @@ def _serve_cf_lifecycle(args):
         fire, reasons = policy.decide(pol, rspec, snap)
         if fire:
             gen = pol.generation + 1
-            rows = np.asarray(bst.state.ratings)[:int(bst.n_valid)]
+            rows = bst.host_ratings(0, int(bst.n_valid))
             # request() declines while the previous refit thread is still
             # winding down; keep the streak and retry next wave instead of
             # marking a refresh that never launched
@@ -506,7 +506,7 @@ def _serve_cf_lifecycle(args):
             cur_n = int(bst.n_valid)
             new_bst = buckets.from_state(st_new, args.min_bucket, args.growth)
             # users folded while the refit ran: fold the delta into the new gen
-            delta = np.asarray(bst.state.ratings)[snap_u:cur_n]
+            delta = bst.host_ratings(snap_u, cur_n)
             bst = buckets.fold_in_rows(new_bst, delta, bq, spec,
                                        args.min_bucket, args.growth)
             caps_used.add((bst.capacity, bst.state.graph.is_compact))
@@ -1587,8 +1587,7 @@ def _serve_cf_engine(args):
             warm_rows = np.asarray(msst0.sstate.state.ratings)[
                 wsh[warm_ids] * msst0.capacity + wsl[warm_ids]]
         else:
-            warm_rows = np.asarray(
-                backend._pub[0].bstate.state.ratings)[warm_ids]
+            warm_rows = backend._pub[0].bstate.host_ratings()[warm_ids]
         backend.apply_update(warm_ids, warm_rows)
         backend.apply_remove(np.zeros(0, np.int64))
         pub = backend.snapshot()

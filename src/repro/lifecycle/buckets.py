@@ -17,6 +17,15 @@ On top of that, every consumer (``knn.predict_pairs_graph``,
 ``knn.recommend_topn_graph``) re-zeroes weights of out-of-range neighbor ids
 via ``n_valid``, so padded rows cannot leak into predictions or
 recommendations even from a corrupted artifact.
+
+The rating matrix is also padded in its columns, with zeros, to a multiple
+of ``LANES``; ``n_items`` keeps the real item count. A TPU lays a
+``(capacity, P)`` f32 array out column-major when ``P`` is not a multiple
+of 128, and a gather of rows from that layout makes XLA transpose the whole
+matrix on every read. A zero column is an item nobody rated: it changes no
+mean, count or similarity, and the top-N program cuts it off its items.
+Writes take rows of ``n_items`` ratings and project them through landmark
+rows of ``n_items`` ratings, exactly as before the padding.
 """
 from __future__ import annotations
 
@@ -35,6 +44,7 @@ from repro.core.types import LandmarkSpec, NeighborGraph
 
 DEFAULT_MIN_BUCKET = 256
 DEFAULT_GROWTH = 2.0
+LANES = 128  # a TPU tile's minor width: the rating matrix's column multiple
 
 
 def bucket_schedule(max_size: int, min_bucket: int = DEFAULT_MIN_BUCKET,
@@ -64,43 +74,71 @@ class BucketedState:
     """A ``LandmarkState`` padded to a bucket capacity + its live-row count.
 
     ``state`` arrays have leading dimension ``capacity``; rows ``< n_valid``
-    are real users, the rest zero filler. The whole thing is a pytree, so the
-    jitted serve/fold steps take it directly; ``n_valid`` is a traced leaf —
-    fill level never triggers a recompile.
+    are real users, the rest zero filler. ``state.ratings`` has
+    ``lane_width(n_items)`` columns, those ``>= n_items`` zero (module
+    docstring). The whole thing is a pytree, so the jitted serve/fold steps
+    take it directly; ``n_valid`` is a traced leaf — fill level never
+    triggers a recompile — and ``n_items`` a static one.
     """
 
     state: LandmarkState
     n_valid: jax.Array  # () int32
+    n_items: int  # real columns of ``state.ratings``
 
     def tree_flatten(self):
-        return (self.state, self.n_valid), ()
+        return (self.state, self.n_valid), (self.n_items,)
 
     @classmethod
     def tree_unflatten(cls, aux, children):
-        return cls(*children)
+        return cls(*children, *aux)
 
     @property
     def capacity(self) -> int:
         return self.state.ratings.shape[0]
 
+    @property
+    def k(self) -> int:
+        return self.state.graph.k
 
-def _pad_rows(x: jax.Array, capacity: int) -> jax.Array:
-    pad = capacity - x.shape[0]
-    assert pad >= 0, (x.shape, capacity)
-    # pad == 0 still copies: the padded state feeds the *donating* fold step,
+    def host_ratings(self, lo: int = 0, hi: int = None):
+        """Rows ``lo:hi`` of the rating matrix on the host, ``n_items``
+        wide: what a refit or a write takes."""
+        import numpy as np
+
+        return np.asarray(self.state.ratings)[lo:hi, :self.n_items]
+
+
+def lane_width(n_items: int) -> int:
+    """The bucketed rating matrix's width: ``n_items`` rounded up to LANES."""
+    return -(-n_items // LANES) * LANES
+
+
+def pad_items(rows: jax.Array, width: int) -> jax.Array:
+    """Rating rows of ``n_items`` columns, zero-padded to ``width``."""
+    return jnp.pad(rows, ((0, 0), (0, width - rows.shape[1])))
+
+
+def _pad_rows(x: jax.Array, capacity: int, width: int = None) -> jax.Array:
+    """``x`` zero-padded to ``capacity`` rows and, given ``width``, that
+    many columns, in one allocation."""
+    pad = [(0, capacity - x.shape[0])] + [(0, 0)] * (x.ndim - 1)
+    if width is not None:
+        pad[1] = (0, width - x.shape[1])
+    assert min(p[1] for p in pad) >= 0, (x.shape, capacity, width)
+    # no pad still copies: the padded state feeds the *donating* fold step,
     # which must never alias the caller's source arrays (jnp.pad already
-    # allocates fresh buffers on the pad > 0 path)
-    return jnp.pad(x, ((0, pad),) + ((0, 0),) * (x.ndim - 1)) if pad \
-        else x.copy()
+    # allocates fresh buffers when it pads)
+    return jnp.pad(x, pad) if any(p[1] for p in pad) else x.copy()
 
 
 def _pad_state(state: LandmarkState, capacity: int) -> LandmarkState:
-    """Zero-pad every user-indexed array to ``capacity`` rows.
+    """Zero-pad every user-indexed array to ``capacity`` rows, and the
+    ratings to ``lane_width`` columns.
 
     Zero filler is inert by construction: zero rating rows have mask 0 and
-    mean 0, zero graph rows have weight 0. No output leaf aliases an input
-    leaf (``landmark_idx`` is copied outright) — donation safety, see
-    :func:`fold_in_bucketed`.
+    mean 0, zero graph rows have weight 0, zero columns are unrated items.
+    No output leaf aliases an input leaf (``landmark_idx`` is copied
+    outright) — donation safety, see :func:`fold_in_bucketed`.
     """
     if state.graph is None:
         raise ValueError("bucketed serving needs a graph-backed state; "
@@ -109,7 +147,8 @@ def _pad_state(state: LandmarkState, capacity: int) -> LandmarkState:
     return LandmarkState(
         state.landmark_idx.copy(),
         _pad_rows(state.representation, capacity),
-        _pad_rows(state.ratings, capacity),
+        _pad_rows(state.ratings, capacity,
+                  lane_width(state.ratings.shape[1])),
         graph=NeighborGraph(_pad_rows(graph.indices, capacity),
                             _pad_rows(graph.weights, capacity)),
     )
@@ -125,7 +164,8 @@ def from_state(state: LandmarkState, min_bucket: int = DEFAULT_MIN_BUCKET,
     """
     u = state.ratings.shape[0]
     cap = bucket_capacity(u, min_bucket, growth)
-    return BucketedState(_pad_state(state, cap), jnp.int32(u))
+    return BucketedState(_pad_state(state, cap), jnp.int32(u),
+                         state.ratings.shape[1])
 
 
 def ensure_capacity(bstate: BucketedState, incoming: int,
@@ -140,13 +180,14 @@ def ensure_capacity(bstate: BucketedState, incoming: int,
     if need <= bstate.capacity:
         return bstate, False
     cap = bucket_capacity(need, min_bucket, growth)
-    return BucketedState(_pad_state(bstate.state, cap), bstate.n_valid), True
+    return BucketedState(_pad_state(bstate.state, cap), bstate.n_valid,
+                         bstate.n_items), True
 
 
 @partial(jax.jit, static_argnames=("spec",), donate_argnums=(0,))
 def fold_in_bucketed(
     bstate: BucketedState,
-    new_ratings: jax.Array,  # (bq, P) batch bucket; rows >= b_valid are filler
+    new_ratings: jax.Array,  # (bq, n_items) batch; rows >= b_valid are filler
     b_valid: jax.Array,  # () int32 real rows in the batch
     spec: LandmarkSpec,
     landmarks: jax.Array = None,  # (n, P) frozen basis override (mutation path)
@@ -167,10 +208,10 @@ def fold_in_bucketed(
     without donation (CPU) this is a no-op.
 
     ``landmarks`` overrides the projection basis. The default re-slices
-    ``st.ratings[landmark_idx]`` — correct while rating rows are immutable,
-    but ``repro.mutation`` updates and zeroes rating rows in place, so the
-    mutable path passes its frozen (n, P) snapshot instead (the basis must
-    not drift between refreshes).
+    ``st.ratings[landmark_idx, :n_items]`` — correct while rating rows are
+    immutable, but ``repro.mutation`` updates and zeroes rating rows in
+    place, so the mutable path passes its frozen (n, P) snapshot instead
+    (the basis must not drift between refreshes).
     """
     st = bstate.state
     n_valid = bstate.n_valid
@@ -178,18 +219,19 @@ def fold_in_bucketed(
     q_valid = (jnp.arange(bq) < b_valid)[:, None]
     new_ratings = jnp.where(q_valid, new_ratings, 0.0)
 
-    if landmarks is None:
-        landmarks = st.ratings[st.landmark_idx]  # (n, P) frozen: ids < U0
+    if landmarks is None:  # (n, P) frozen: ids < U0
+        landmarks = st.ratings[st.landmark_idx, :bstate.n_items]
     new_rep = masked_similarity(new_ratings, landmarks, spec.d1)  # (bq, n)
     new_rep = jnp.where(q_valid, new_rep, 0.0)
 
-    ratings = jax.lax.dynamic_update_slice(st.ratings, new_ratings, (n_valid, 0))
+    ratings = jax.lax.dynamic_update_slice(
+        st.ratings, pad_items(new_ratings, st.ratings.shape[1]), (n_valid, 0))
     rep = jax.lax.dynamic_update_slice(st.representation, new_rep, (n_valid, 0))
     graph = extend_neighbor_graph_bucketed(st.graph, rep, new_rep,
                                            n_valid, b_valid, spec.d2)
     return BucketedState(
         LandmarkState(st.landmark_idx, rep, ratings, graph=graph),
-        n_valid + b_valid.astype(jnp.int32),
+        n_valid + b_valid.astype(jnp.int32), bstate.n_items,
     )
 
 
@@ -209,7 +251,7 @@ def fold_in_rows(bstate: BucketedState, rows, bq: int, spec: LandmarkSpec,
     n = len(rows)
     bstate, _ = ensure_capacity(bstate, -(-n // bq) * bq if n else 0,
                                 min_bucket, growth)
-    p = bstate.state.ratings.shape[1]
+    p = bstate.n_items
     rows = jnp.asarray(rows)
     for lo in range(0, n, bq):
         chunk = rows[lo:lo + bq]
@@ -227,9 +269,11 @@ def predict_pairs(bstate: BucketedState, users: jax.Array, items: jax.Array
 
 
 def recommend_topn(bstate: BucketedState, users: jax.Array, n: int = 10):
-    """Serve-path top-N with the padded-row mask threaded through."""
+    """Serve-path top-N with the padded-row mask threaded through; the
+    padding columns are no item."""
     return knn.recommend_topn_graph(bstate.state.graph, bstate.state.ratings,
-                                    users, n=n, n_valid=bstate.n_valid)
+                                    users, n=n, n_valid=bstate.n_valid,
+                                    n_items=bstate.n_items)
 
 
 def compact_state(bstate: BucketedState) -> BucketedState:
@@ -246,7 +290,7 @@ def compact_state(bstate: BucketedState) -> BucketedState:
     return BucketedState(
         LandmarkState(st.landmark_idx, st.representation, st.ratings,
                       graph=st.graph.to_compact()),
-        bstate.n_valid)
+        bstate.n_valid, bstate.n_items)
 
 
 # ---------------------------------------------------------------------------

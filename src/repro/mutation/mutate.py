@@ -104,6 +104,10 @@ class MutableState:
         return self.bstate.capacity
 
     @property
+    def k(self) -> int:
+        return self.bstate.k
+
+    @property
     def n_valid(self) -> jax.Array:
         """High-water append mark — tombstoned rows still count until
         compaction (live rows = ``n_valid - tomb.sum()``)."""
@@ -129,7 +133,7 @@ def from_bucketed(bstate: buckets.BucketedState) -> MutableState:
     cap = bstate.capacity
     return MutableState(
         bstate,
-        jnp.asarray(st.ratings[st.landmark_idx]),
+        jnp.asarray(st.ratings[st.landmark_idx, :bstate.n_items]),
         jnp.zeros((cap,), bool),
         jnp.zeros((cap,), bool),
     )
@@ -158,7 +162,7 @@ def _grow_masks(mst: MutableState, bstate: buckets.BucketedState
 def update_ratings(
     mst: MutableState,
     ids: jax.Array,  # (b,) row ids to replace; entries >= b_valid are filler
-    rows: jax.Array,  # (b, P) full replacement rating rows (0 == un-rated)
+    rows: jax.Array,  # (b, n_items) full replacement rows (0 == un-rated)
     b_valid: jax.Array,  # () int32 real entries in the batch
     spec: LandmarkSpec,
 ) -> MutableState:
@@ -192,7 +196,8 @@ def update_ratings(
     new_rep = masked_similarity(rows, mst.landmarks, spec.d1)  # (b, n)
     new_rep = jnp.where(eff[:, None], new_rep, 0.0)
 
-    ratings = st.ratings.at[safe_ids].set(rows, mode="drop")
+    ratings = st.ratings.at[safe_ids].set(
+        buckets.pad_items(rows, st.ratings.shape[1]), mode="drop")
     rep = st.representation.at[safe_ids].set(new_rep, mode="drop")
 
     changed = jnp.zeros((cap,), bool).at[safe_ids].set(eff, mode="drop")
@@ -223,7 +228,7 @@ def update_ratings(
     return MutableState(
         buckets.BucketedState(
             LandmarkState(st.landmark_idx, rep, ratings, graph=graph),
-            n_valid),
+            n_valid, bst.n_items),
         mst.landmarks, mst.tomb, dirty)
 
 
@@ -283,7 +288,7 @@ def remove_users(
         buckets.BucketedState(
             LandmarkState(st.landmark_idx, rep, ratings,
                           graph=NeighborGraph(gi, gw)),
-            n_valid),
+            n_valid, bst.n_items),
         mst.landmarks, tomb, dirty)
 
 
@@ -335,7 +340,6 @@ def _rescan_topk(
     return vals, idx
 
 
-@partial(jax.jit, static_argnames=("bq", "spec", "chunk", "nprobe"))
 def repair(
     mst: MutableState,
     bq: int,
@@ -355,7 +359,29 @@ def repair(
     cells — O(bq·(U/C)·nprobe·n) candidate generation, exact at full probe;
     without one it is a chunked full scan over the live prefix. Tombstoned
     candidates are masked either way.
+
+    The compiled step returns only the neighbour lists and the dirty map;
+    the new state shares every other array with ``mst``. A jitted function
+    copies each array it returns, and a copy of the rating matrix per
+    round would hold a third matrix on the device during a write.
     """
+    graph, dirty, n = _repair_lists(mst, bq, spec, chunk=chunk,
+                                    ivf_index=ivf_index, nprobe=nprobe)
+    bst = mst.bstate
+    st = bst.state
+    out = MutableState(
+        buckets.BucketedState(
+            LandmarkState(st.landmark_idx, st.representation, st.ratings,
+                          graph=graph),
+            bst.n_valid, bst.n_items),
+        mst.landmarks, mst.tomb, dirty)
+    return out, n
+
+
+@partial(jax.jit, static_argnames=("bq", "spec", "chunk", "nprobe"))
+def _repair_lists(mst: MutableState, bq: int, spec: LandmarkSpec, *,
+                  chunk: int, ivf_index, nprobe: Optional[int]):
+    """:func:`repair`'s compiled step: ``(graph, dirty, n_repaired)``."""
     bst = mst.bstate
     st = bst.state
     cap = bst.capacity
@@ -387,14 +413,7 @@ def repair(
     gi = graph.indices.at[sel].set(fixed.indices, mode="drop")
     gw = graph.weights.at[sel].set(fixed.weights, mode="drop")
     dirty = mst.dirty.at[sel].set(False, mode="drop")
-
-    out = MutableState(
-        buckets.BucketedState(
-            LandmarkState(st.landmark_idx, st.representation, st.ratings,
-                          graph=NeighborGraph(gi, gw)),
-            n_valid),
-        mst.landmarks, mst.tomb, dirty)
-    return out, jnp.sum(active.astype(jnp.int32))
+    return NeighborGraph(gi, gw), dirty, jnp.sum(active.astype(jnp.int32))
 
 
 def drain_repairs(mst: MutableState, spec: LandmarkSpec, bq: int = 64,
@@ -480,7 +499,7 @@ def _compact_tombstones_body(mst, bst, st, cap, n_valid, tomb):
                           gather(st.representation), gather(st.ratings),
                           graph=NeighborGraph(gather(graph.indices),
                                               gather(graph.weights))),
-            jnp.int32(n_live)),
+            jnp.int32(n_live), bst.n_items),
         mst.landmarks,
         jnp.zeros((cap,), bool), jnp.zeros((cap,), bool))
 
@@ -502,7 +521,7 @@ def fold_in_rows(mst: MutableState, rows, bq: int, spec: LandmarkSpec,
     bst, _ = buckets.ensure_capacity(mst.bstate, -(-n // bq) * bq if n else 0,
                                      min_bucket, growth)
     mst = _grow_masks(mst, bst)
-    p = bst.state.ratings.shape[1]
+    p = bst.n_items
     rows = jnp.asarray(rows)
     for lo in range(0, n, bq):
         chunk = rows[lo:lo + bq]
@@ -538,7 +557,7 @@ def fold_in_mutable(mst: MutableState, new_ratings: jax.Array,
         buckets.BucketedState(
             LandmarkState(bst.state.landmark_idx, bst.state.representation,
                           bst.state.ratings, graph=graph),
-            bst.n_valid),
+            bst.n_valid, bst.n_items),
         mst.landmarks, mst.tomb, dirty)
 
 
@@ -557,4 +576,4 @@ def recommend_topn(mst: MutableState, users: jax.Array, n: int = 10):
     bst = mst.bstate
     return knn.recommend_topn_graph(bst.state.graph, bst.state.ratings,
                                     users, n=n, n_valid=bst.n_valid,
-                                    tomb=mst.tomb)
+                                    tomb=mst.tomb, n_items=bst.n_items)

@@ -53,6 +53,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import obs as obslib
+from repro.core import knn
 from repro.lifecycle import buckets
 from repro.obs.registry import Histogram
 from repro.serving.stats import histogram_latency, latency_stats
@@ -161,6 +162,14 @@ class LocalBackend:
     def snapshot(self):
         return self._pub
 
+    def gathers_row_stats(self, kind: str, rows: int) -> bool:
+        """Whether a read batch of ``rows`` takes Eq. (1)'s row statistics
+        from its gathered rows: top-N always, pairs by
+        ``knn.gathers_row_stats`` at the live generation's capacity and k."""
+        st = self._pub[0]
+        return kind == "topn" or knn.gathers_row_stats(rows, st.k,
+                                                        st.capacity)
+
     def predict_pairs(self, pub, users: np.ndarray, items: np.ndarray):
         bst, _ = pub
         return buckets.predict_pairs(bst, jnp.asarray(users, jnp.int32),
@@ -228,6 +237,11 @@ class ShardedBackend:
 
     def snapshot(self):
         return self._pub
+
+    @staticmethod
+    def gathers_row_stats(kind: str, rows: int) -> bool:
+        """The router takes row statistics from each shard's whole block."""
+        return False
 
     @staticmethod
     def _sharded_ids(pub, users: np.ndarray) -> jnp.ndarray:
@@ -970,8 +984,13 @@ class RequestEngine:
             reg.counter(f"engine.completed.{k}").set(self.completed[k])
             reg.publish_histogram(f"engine.latency_ms.{k}",
                                   self.latencies[k])
+        gathered = {k: 0 for k in READ_KINDS}
         for (kind, shape), c in list(self.launches.items()):
             reg.counter(f"exec.engine.{kind}.b{shape}.launches").set(c)
+            if self.backend.gathers_row_stats(kind, shape):
+                gathered[kind] += c
+        for kind, c in gathered.items():
+            reg.counter(f"exec.engine.{kind}.gathered_stats").set(c)
         reg.counter("engine.batches").set(self.batches)
         reg.counter("engine.exec_rows").set(self.exec_rows)
         reg.counter("engine.pad_rows").set(self.pad_rows)

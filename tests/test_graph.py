@@ -360,3 +360,126 @@ def test_neighbor_graph_pytree_roundtrip():
     leaves, treedef = jax.tree_util.tree_flatten(g)
     g2 = jax.tree_util.tree_unflatten(treedef, leaves)
     assert isinstance(g2, NeighborGraph) and g2.n_nodes == 4 and g2.k == 2
+
+
+# ------------------------------------------- serve: gathered row statistics
+
+ROWS_K, ROWS_P, ROWS_VALID, ROWS_CAP = 3, 10, 10, 12
+# user 0 cites a cold, a padded and a tombstoned row; 2 rated every item; 4 is cold
+ROWS_USERS = [0, 5, 2, 4]
+
+
+def _row_stats_state(cap):
+    """A ``cap``-row bucket: rows ``>= ROWS_VALID`` are padding holding stale
+    ratings; user 0's neighbours are a cold (all-zero) row, a padded row and
+    a tombstoned row; user 2 has rated every item. Rows past ``ROWS_CAP``
+    are empty bucket slots."""
+    rng = np.random.default_rng(7)
+    r = rng.integers(1, 6, (ROWS_CAP, ROWS_P)).astype(np.float32)
+    r *= rng.random((ROWS_CAP, ROWS_P)) < 0.5
+    r[4] = 0.0
+    r[2] = rng.integers(1, 6, ROWS_P)
+    idx = rng.integers(0, ROWS_CAP, (ROWS_CAP, ROWS_K)).astype(np.int32)
+    idx[0] = [4, 11, 7]
+    w = rng.standard_normal((ROWS_CAP, ROWS_K)).astype(np.float32)
+    tomb = np.zeros(ROWS_CAP, bool)
+    tomb[7] = True
+    pad = cap - ROWS_CAP
+    graph = NeighborGraph(jnp.asarray(np.pad(idx, ((0, pad), (0, 0)))),
+                          jnp.asarray(np.pad(w, ((0, pad), (0, 0)))))
+    return (graph, jnp.asarray(np.pad(r, ((0, pad), (0, 0)))),
+            jnp.asarray(np.pad(tomb, (0, pad))))
+
+
+def _read(kind, cap, users):
+    graph, ratings, tomb = _row_stats_state(cap)
+    users = jnp.asarray(users, jnp.int32)
+    kw = dict(n_valid=jnp.int32(ROWS_VALID), tomb=tomb)
+    if kind == "pair":
+        items = (users * 3 + 1) % ROWS_P
+        return (np.asarray(knn.predict_pairs_graph(graph, ratings, users,
+                                                   items, **kw)),)
+    it, sc = knn.recommend_topn_graph(graph, ratings, users, n=4, **kw)
+    return np.asarray(it), np.asarray(sc)
+
+
+def _topn_whole_matrix(users, n=4):
+    """Top-N with the row statistics of one pass over the whole matrix:
+    the oracle of the gathered-row top-N program."""
+    graph, ratings, tomb = _row_stats_state(64)
+    users = jnp.asarray(users, jnp.int32)
+    mask, means, centered = knn._center(ratings)
+    idx = graph.indices[users]
+    w = knn._mask_padded_rows(idx, graph.weights[users],
+                              jnp.int32(ROWS_VALID), tomb=tomb)
+    preds = knn._block_predict(w, centered[idx], mask[idx], means[users])
+    preds = jnp.where(mask[users] > 0, -jnp.inf, preds)
+    scores, items = jax.lax.top_k(preds, n)
+    return (np.asarray(jnp.where(jnp.isfinite(scores), items, -1)),
+            np.asarray(scores))
+
+
+@pytest.mark.parametrize("b, cap, gathered", [(2, ROWS_CAP, True),
+                                              (4, ROWS_CAP, False),
+                                              (4, 64, True)])
+@pytest.mark.parametrize("kind", ["pair", "topn"])
+def test_gathered_row_stats_bitwise_vs_whole_matrix(kind, b, cap, gathered):
+    """Eq. (1)'s row statistics taken from the gathered rows give the same
+    bits as the pass over the whole matrix, through padding, tombstones, a
+    cold neighbour and an exhausted user. Pair batches of ``b`` rows fall
+    on either side of the shape rule and are held to a whole-matrix pair
+    batch; the top-N program always gathers and is held to its oracle."""
+    assert knn.gathers_row_stats(b, ROWS_K, cap) is gathered
+    users = (ROWS_USERS * 8)[:b]
+    ref_b = 20  # 20 * (k + 1) rows > 64: the whole-matrix path
+    assert not knn.gathers_row_stats(ref_b, ROWS_K, 64)
+    want = (_read(kind, 64, (ROWS_USERS * 8)[:ref_b]) if kind == "pair"
+            else _topn_whole_matrix((ROWS_USERS * 8)[:ref_b]))
+    got = _read(kind, cap, users)
+    for g, w_ in zip(got, want):
+        assert np.array_equal(g, w_[:b])
+    assert np.isfinite(got[-1][:2]).all()
+    if kind == "topn" and b > 2:  # user 2 rated every item: all exhausted
+        assert (got[0][2] == -1).all() and np.isneginf(got[1][2]).all()
+
+
+def test_topn_on_widened_rows_never_lists_padding_columns():
+    """Zero columns of a lane-aligned rating matrix (``buckets``) are no
+    item: with ``n_items`` the lists equal those of the matrix as it is,
+    and an exhausted user gets -1 / -inf slots, never a padding column."""
+    graph, ratings, tomb = _row_stats_state(ROWS_CAP)
+    wide = jnp.pad(ratings, ((0, 0), (0, 128 - ROWS_P)))
+    users = jnp.asarray(ROWS_USERS, jnp.int32)
+    kw = dict(n=4, n_valid=jnp.int32(ROWS_VALID), tomb=tomb)
+    wi, ws = knn.recommend_topn_graph(graph, ratings, users, **kw)
+    gi, gs = knn.recommend_topn_graph(graph, wide, users, n_items=ROWS_P,
+                                      **kw)
+    assert np.array_equal(np.asarray(gi), np.asarray(wi))
+    assert np.array_equal(np.asarray(gs), np.asarray(ws))
+    assert (np.asarray(gi)[2] == -1).all()
+
+
+@pytest.mark.parametrize("b", [8, 128])
+@pytest.mark.parametrize("kind", ["pair", "topn"])
+def test_read_programs_hold_no_row_space_intermediate(kind, b):
+    """At serving shapes the traced read programs hold nothing with the
+    matrix's full row dimension apart from their inputs: the mask, means
+    and centred values come from the (b, k, P) gathered rows."""
+    k, p = 13, 64
+    cap = 16 * b * (k + 1)
+    sds = jax.ShapeDtypeStruct
+    graph = NeighborGraph(sds((cap, k), jnp.int32), sds((cap, k), jnp.float32))
+    args = (graph, sds((cap, p), jnp.float32), sds((b,), jnp.int32))
+    kw = dict(n_valid=sds((), jnp.int32), tomb=sds((cap,), jnp.bool_))
+    if kind == "pair":
+        def read(g, r, u, nv, t):
+            return knn.predict_pairs_graph(g, r, u, u, n_valid=nv, tomb=t)
+    else:
+        def read(g, r, u, nv, t):
+            return knn.recommend_topn_graph(g, r, u, n=10, n_valid=nv,
+                                            tomb=t)
+    jaxpr = jax.make_jaxpr(read)(*args, *kw.values())
+    avals = _all_avals(jaxpr.jaxpr, [])
+    offender = [a for a in avals if cap in getattr(a, "shape", ())]
+    assert not offender, f"row-space intermediates: {offender[:3]}"
+    assert any(getattr(a, "shape", None) == (b, k, p) for a in avals)

@@ -21,13 +21,15 @@ import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 import numpy as np  # noqa: E402
 
-from repro.core import LandmarkSpec, RatingMatrix  # noqa: E402
+from repro.core import LandmarkSpec, RatingMatrix, knn  # noqa: E402
 from repro.core.landmark_cf import fit  # noqa: E402
 from repro.launch.mesh import make_mesh  # noqa: E402
 from repro.lifecycle import buckets  # noqa: E402
+from repro.obs import Observability  # noqa: E402
 from repro.serving import (  # noqa: E402
     EngineConfig,
     LocalBackend,
+    MutableLocalBackend,
     RequestEngine,
     ShardedBackend,
     latency_stats,
@@ -188,6 +190,73 @@ def test_oversized_request_rejected(state):
     with pytest.raises(ValueError, match="max_batch"):
         eng.submit("pair", users=np.zeros(CFG.max_batch + 1, int),
                    items=np.zeros(CFG.max_batch + 1, int))
+
+
+@pytest.mark.parametrize("backend_cls", [LocalBackend, MutableLocalBackend])
+def test_gathered_stats_counter_follows_shape_rule(state, backend_cls):
+    """``exec.engine.<kind>.gathered_stats`` counts the launches whose batch
+    shape takes Eq. (1)'s row statistics from the gathered rows, and leaves
+    out those that pass over the whole matrix: every top-N launch, the pair
+    launches by the shape rule."""
+    backend = backend_cls(buckets.from_state(state, min_bucket=U), SPEC,
+                          min_bucket=U)
+    # 4 * (k + 1) = 24 rows <= 64 < 16 * (k + 1) = 96
+    assert backend.gathers_row_stats("pair", 4)
+    assert not backend.gathers_row_stats("pair", 16)
+    assert backend.gathers_row_stats("topn", 16)
+    o = Observability(sample_rate=0.0, seed=0)
+    eng = RequestEngine(backend, CFG, obs=o)
+    rng = np.random.default_rng(5)
+    for kind, m in [("pair", 3), ("pair", 16), ("pair", 2), ("topn", 1),
+                    ("topn", 12)]:
+        uu = rng.integers(0, U, m)
+        items = rng.integers(0, P, m) if kind == "pair" else None
+        assert eng.submit(kind, users=uu, items=items) is not None
+        assert eng.pump_reads() == 1
+    eng.publish_metrics()
+    c = o.registry.snapshot()["counters"]
+    assert c["exec.engine.pair.b4.launches"] == 2
+    assert c["exec.engine.pair.b16.launches"] == 1
+    assert c["exec.engine.pair.gathered_stats"] == 2
+    assert c["exec.engine.topn.b4.launches"] == 1
+    assert c["exec.engine.topn.b16.launches"] == 1
+    assert c["exec.engine.topn.gathered_stats"] == 2
+
+
+@pytest.mark.parametrize("backend_cls", [LocalBackend, MutableLocalBackend])
+def test_backends_serve_a_lane_aligned_state(state, backend_cls):
+    """A local backend's rating matrix is zero-padded to a multiple of 128
+    columns through writes, and it serves the same bits as the read
+    programs on the fitted, unpadded state."""
+    backend = backend_cls(buckets.from_state(state, min_bucket=U), SPEC,
+                          min_bucket=U)
+    rng = np.random.default_rng(8)
+    users, items = rng.integers(0, U, 8), rng.integers(0, P, 8)
+    ju, ji = jnp.asarray(users, jnp.int32), jnp.asarray(items, jnp.int32)
+    got = backend.predict_pairs(backend.snapshot(), users, items)
+    want = knn.predict_pairs_graph(state.graph, state.ratings, ju, ji)
+    assert np.array_equal(np.asarray(got), np.asarray(want))
+    gi, gs = backend.recommend_topn(backend.snapshot(), users, 5)
+    wi, ws = knn.recommend_topn_graph(state.graph, state.ratings, ju, n=5)
+    assert np.array_equal(np.asarray(gi), np.asarray(wi))
+    assert np.array_equal(np.asarray(gs), np.asarray(ws))
+
+    rows = _ratings(2, P, seed=12)
+    if backend_cls is MutableLocalBackend:
+        backend.apply_update(np.array([1, 7]), rows)
+        backend.apply_remove(np.array([3]))
+        at = [1, 7]
+    else:
+        backend.fold_in(rows, 8)
+        at = [U, U + 1]
+    bst = backend.snapshot()[0]
+    bst = getattr(bst, "bstate", bst)
+    r = np.asarray(bst.state.ratings)
+    assert bst.n_items == P and r.shape[1] == 128
+    assert not r[:, P:].any()
+    assert np.array_equal(r[at, :P], rows)
+    if backend_cls is MutableLocalBackend:
+        assert not r[3].any()
 
 
 # ---------------------------------------------------------------- fold lane

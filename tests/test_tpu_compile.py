@@ -1,11 +1,14 @@
-"""Compile-only checks of the main-path Pallas kernels for a TPU v5e.
+"""Compile-only checks of the main-path Pallas kernels and read programs for
+a TPU v5e.
 
 Nothing runs: each kernel is lowered at MovieLens-1M serving widths
 (U=6040 users, n=20 landmarks, k=13 neighbours, the registry's
 ``landmark_cf`` model) and compiled by the TPU compiler for a *described*
 v5e chip, which refuses what interpret mode accepts (block shapes that
 break the (8, 128) tiling rule, VMEM overruns). A kernel that compiles must
-show up in the executable as a ``tpu_custom_call``.
+show up in the executable as a ``tpu_custom_call``. The read programs are
+compiled at the MovieLens-10M bucket (131,072 x 10,681) for what they hold
+in temporaries.
 
 The topology is described inside a fixture, never at import: only one
 process may load the TPU library, and every xdist worker imports this file.
@@ -15,6 +18,7 @@ import pytest
 jax = pytest.importorskip("jax")
 import jax.numpy as jnp  # noqa: E402
 
+from repro.core.types import NeighborGraph  # noqa: E402
 from repro.kernels.ivf_probe import fused_probe_topk  # noqa: E402
 from repro.kernels.knn_topk import (  # noqa: E402
     foldin_topk_kernel, topk_sim_kernel)
@@ -118,3 +122,37 @@ def test_score_candidates_kernel_compiles(one_chip):
     _assert_kernel(
         lambda a, b: score_candidates_kernel(a, b, interpret=False), q, cand)
 
+
+@pytest.mark.parametrize("kind", ["pair", "topn"])
+def test_read_programs_copy_no_matrix_of_a_lane_aligned_state(one_chip,
+                                                              kind):
+    """At the ML-10M bucket, both read programs of the mutable serving
+    state hold at most their gathered (B, k, P) rows in temporaries: the
+    bucketed rating matrix is lane-aligned, so the TPU lays it out
+    row-major and a row gather reads only its rows."""
+    from repro import mutation
+    from repro.core.landmark_cf import LandmarkState
+    from repro.lifecycle import buckets
+
+    cap, p, b = 131_072, 10_681, 128
+    width = buckets.lane_width(p)
+    block = b * K * width * 4  # the (B, k, P) f32 rows
+    bst = buckets.BucketedState(
+        LandmarkState(_spec((N,), jnp.int32, one_chip),
+                      _spec((cap, N), jnp.float32, one_chip),
+                      _spec((cap, width), jnp.float32, one_chip),
+                      graph=NeighborGraph(
+                          _spec((cap, K), jnp.int32, one_chip),
+                          _spec((cap, K), jnp.float32, one_chip))),
+        _spec((), jnp.int32, one_chip), p)
+    mst = mutation.MutableState(bst, _spec((N, p), jnp.float32, one_chip),
+                                _spec((cap,), jnp.bool_, one_chip),
+                                _spec((cap,), jnp.bool_, one_chip))
+    users = _spec((b,), jnp.int32, one_chip)
+    if kind == "pair":
+        read = jax.jit(mutation.predict_pairs).lower(mst, users, users)
+    else:
+        read = jax.jit(mutation.recommend_topn,
+                       static_argnames="n").lower(mst, users, n=10)
+    temp = read.compile().memory_analysis().temp_size_in_bytes
+    assert temp <= 1.5 * block, (temp, block)
