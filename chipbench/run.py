@@ -35,8 +35,10 @@ import importlib.util  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import shutil  # noqa: E402
+import statistics  # noqa: E402
 import sys  # noqa: E402
 import threading  # noqa: E402
+import traceback  # noqa: E402
 import types  # noqa: E402
 from pathlib import Path  # noqa: E402
 
@@ -50,6 +52,8 @@ DRAIN_S = 60.0          # how long a request due in the window may take
 TRACE_AT = 0.4          # the capture starts this far into the window
 TRACE_S = 2.0           # and lasts this long
 KEEP_GENERATIONS = 256  # write-cell generations whose reads are checked
+COLLECTIVES = ("all-reduce", "all-gather", "collective-permute",
+               "reduce-scatter", "all-to-all")  # HLO names, logged when traced
 for p in (str(ROOT), str(ROOT / "src")):
     if p not in sys.path:
         sys.path.insert(0, p)
@@ -137,35 +141,168 @@ class GcPauses:
 
 class Stalls:
     """Oversleeps of a thread that sleeps 5 ms at a time: the moments the
-    whole host stood still, as seconds since the process started and
-    seconds lost. The chip's host stalls ~0.1 s every 5-20 s and, at
-    random, for seconds; a run that reads far off shows why here."""
+    process stood still. On the chip's machine it stalls ~0.1 s every
+    5-20 s and, at random, for seconds; a run that reads far off shows why
+    here.
+
+    Each stall of 50 ms or more is logged on stderr (nothing of it enters
+    the result line) with what tells its causes apart:
+
+    - ``witness``: whether a second process, which sleeps 5 ms at a time
+      too, overslept over the same stretch (the host held both back) or
+      ran on (this process alone stood still);
+    - ``cpu``: the process's CPU seconds over it, and ``threads`` each
+      Python thread's: a Python thread that ran through it held the
+      interpreter lock;
+    - ``ticks``: the host's CPU ticks (all kinds, all CPUs, ``/proc/stat``)
+      over it, beside ``tick_rate``, their rate over ordinary stretches;
+    - ``stacks``: every Python thread's stack as this thread finds them
+      when it runs again. A ``faulthandler`` watchdog would catch them
+      inside the stall, but it reads the threads without the lock and
+      crashed the process."""
 
     STEP = 0.005
+    LOGGED = 0.05
+    RATE = 0.1  # the stretch of one tick-rate reading
+    WITNESS = (
+        "import os, time\n"
+        "parent, step, t = os.getppid(), %r, time.monotonic()\n"
+        "while os.getppid() == parent:\n"
+        "    time.sleep(step)\n"
+        "    now = time.monotonic()\n"
+        "    if now - t - step > %r:\n"
+        "        print(t, now - t - step, flush=True)\n"
+        "    t = now\n")
 
     def __init__(self):
-        self.seen = []
+        import subprocess
+
+        self.seen, self.tick_rate = [], []
         self._stop = threading.Event()
+        self._stat = os.open("/proc/stat", os.O_RDONLY)
+        self.witness = subprocess.Popen(
+            [sys.executable, "-c", self.WITNESS % (self.STEP, self.LOGGED)],
+            stdout=subprocess.PIPE)
+        os.set_blocking(self.witness.stdout.fileno(), False)
+        self._held = b""
         self.thread = threading.Thread(target=self._watch, name="stalls",
                                        daemon=True)
         self.thread.start()
 
+    def _ticks(self) -> bytes:
+        """The first line of ``/proc/stat``, parsed only for a stall."""
+        return os.pread(self._stat, 256, 0).split(b"\n", 1)[0]
+
+    @staticmethod
+    def _clocks() -> dict:
+        """Each live Python thread's CPU clock, by name: Linux's id of a
+        thread's scheduler CPU clock, made from its thread id as the
+        kernel's ``MAKE_THREAD_CPUCLOCK`` does (a thread gone since then
+        reads as no clock, never as another's)."""
+        return {th.name: (~th.native_id << 3) | 6
+                for th in threading.enumerate() if th.native_id}
+
+    @staticmethod
+    def _cpu(clocks: dict) -> dict:
+        out = {}
+        for name, clk in clocks.items():
+            try:
+                out[name] = time.clock_gettime(clk)
+            except OSError:
+                pass
+        return out
+
     def _watch(self) -> None:
-        t = time.monotonic()
+        def total(line: bytes) -> int:
+            return sum(int(v) for v in line.split()[1:])
+
+        clocks = self._clocks()
+        t = rated = time.monotonic()
+        cpu, threads, ticks = (time.process_time(), self._cpu(clocks),
+                               self._ticks())
+        ticks_rated = ticks
         while not self._stop.wait(self.STEP):
-            now = time.monotonic()
-            if now - t - self.STEP > 0.05:
-                self.seen.append((t - T_START, now - t - self.STEP))
-            t = now
+            now, cpu_now, threads_now, ticks_now = (
+                time.monotonic(), time.process_time(), self._cpu(clocks),
+                self._ticks())
+            lost = now - t - self.STEP
+            if lost > self.LOGGED:
+                frames = sys._current_frames()
+                names = {th.ident: th.name for th in threading.enumerate()}
+                self.seen.append(types.SimpleNamespace(
+                    t=t, at=t - T_START, lost=lost, cpu=cpu_now - cpu,
+                    threads={k: round(v - threads[k], 4)
+                             for k, v in threads_now.items() if k in threads},
+                    ticks=total(ticks_now) - total(ticks),
+                    stacks={names.get(k, k): traceback.format_stack(f)[-4:]
+                            for k, f in frames.items()}))
+                rated, ticks_rated = now, ticks_now
+            elif now - rated >= self.RATE:
+                self.tick_rate.append((total(ticks_now) - total(ticks_rated))
+                                      * self.RATE / (now - rated))
+                rated, ticks_rated = now, ticks_now
+                clocks = self._clocks()
+                threads_now = self._cpu(clocks)
+            t, cpu, threads, ticks = now, cpu_now, threads_now, ticks_now
 
     def stop(self) -> None:
         self._stop.set()
         self.thread.join()
+        self.witness.kill()
+        self.witness.wait()
+        self.witness.stdout.close()
+        os.close(self._stat)
+
+    def _witnessed(self) -> list:
+        """The witness's oversleeps so far: (start, seconds lost)."""
+        try:
+            self._held += os.read(self.witness.stdout.fileno(), 1 << 16)
+        except BlockingIOError:
+            pass
+        return [tuple(map(float, ln.split()))
+                for ln in self._held.decode().splitlines()]
+
+    def report(self, t_window: float, seconds: float) -> None:
+        """Each stall on stderr: when, how long, its readings and, inside
+        the window, the stacks found after it."""
+        q = (statistics.quantiles(self.tick_rate, n=4)
+             if len(self.tick_rate) > 1 else [])
+        log(f"stalls: window {t_window:.3f}-{t_window + seconds:.3f} s "
+            f"after start; host ticks per {self.RATE} s elsewhere: "
+            f"quartiles {[round(v, 1) for v in q]} over "
+            f"{len(self.tick_rate)} stretches")
+        held = self._witnessed()
+        for s in self.seen:
+            inside = t_window <= s.at < t_window + seconds
+            s.witness = any(w0 < s.t + self.STEP + s.lost and s.t < w0 + d
+                            for w0, d in held)
+            log(f"stall {s.at:.3f} s +{s.lost * 1e3:.0f} ms "
+                f"({'in' if inside else 'outside'} the window): witness "
+                f"{'stalled too' if s.witness else 'ran on'}; process cpu "
+                f"{s.cpu:.3f} s, by Python thread {json.dumps(s.threads)}, "
+                f"other threads {s.cpu - sum(s.threads.values()):.3f} s; "
+                f"host ticks {s.ticks}")
+            for name, stack in (s.stacks.items() if inside else ()):
+                log(f"  | {name}: "
+                    + " < ".join(ln.strip().splitlines()[0]
+                                 for ln in reversed(stack)))
 
 
 # ------------------------------------------------------------- deployment
 def build(c, seed: int):
-    """Data, fit and the served backend; returns (backend, data, timings)."""
+    """Data, fit and the served backend, as the configuration's
+    ``serving.backend`` says; returns (backend, data, spec, timings)."""
+    backend = c.cfg["serving"]["backend"]
+    if backend == "MutableLocalBackend":
+        return build_local(c, seed)
+    if backend == "MutableShardedBackend":
+        return build_sharded(c, seed)
+    raise SystemExit(f"chipbench: no build for serving.backend {backend!r}")
+
+
+def build_local(c, seed: int):
+    """One device: the dense matrix, ``fit``, the bucketed state and
+    ``MutableLocalBackend``."""
     import jax
     import jax.numpy as jnp
 
@@ -198,6 +335,59 @@ def build(c, seed: int):
     jax.block_until_ready(inner.snapshot()[0].bstate.state.ratings)
     t["state"] = time.monotonic() - t0
     return Served(inner, program_view), ratings, spec, t
+
+
+def build_sharded(c, seed: int):
+    """The cell's chips as one ``pod`` mesh, built as the program's engine
+    mode builds it: ``fit_distributed``, the state block-partitioned by
+    ``buckets.from_state_sharded`` and ``MutableShardedBackend`` over the
+    logical id -> (shard, slot) tables. The ratings go to the fit as a host
+    array: JAX shards no array whose rows the chips do not divide, and
+    neither MovieLens user count is a multiple of 4, so the harness places
+    no whole (U, P) matrix on a device itself."""
+    import jax
+
+    from chipbench.served import Served, sharded_view
+    from repro.configs import registry
+    from repro.core.landmark_cf import fit_distributed
+    from repro.launch.mesh import make_mesh
+    from repro.lifecycle import buckets
+    from repro.serving import MutableShardedBackend
+
+    if c.cfg["fit_graph_backend"] != "streaming":
+        # fit_distributed has no other graph build
+        raise SystemExit("chipbench: a MutableShardedBackend deployment "
+                         "fits its graph on the streaming path only; "
+                         f"fit_graph_backend is {c.cfg['fit_graph_backend']!r}")
+    chips = c.cell["chips"]
+    t = {}
+    t0 = time.monotonic()
+    ratings = datalib.synthesize(c.cfg, seed)
+    u, p = ratings.n_users, ratings.n_items
+    dense = np.zeros((u, p), np.float32)
+    dense[ratings.users, ratings.items] = ratings.values
+    t["data"] = time.monotonic() - t0
+    t0 = time.monotonic()
+    mesh = make_mesh((chips,), ("pod",), devices=jax.devices()[:chips])
+    spec = registry.get(c.cfg["model"]).model
+    st = fit_distributed(jax.random.PRNGKey(seed & 0x7FFFFFFF), dense, spec,
+                         mesh, user_axes=("pod",))
+    jax.block_until_ready(st.graph.weights)
+    t["fit"] = time.monotonic() - t0
+    t0 = time.monotonic()
+    # the engine mode's per-shard bucket: the deployment's capacity split
+    # over the shards (launch/serve.py)
+    cap = max(8, c.cfg["serving"]["capacity"] // chips)
+    sst = buckets.from_state_sharded(st, mesh, ("pod",), cap)
+    del st, dense
+    u_per = -(-u // chips)
+    ids = np.arange(u)
+    inner = MutableShardedBackend(sst, (ids // u_per).astype(np.int32),
+                                  (ids % u_per).astype(np.int32), spec,
+                                  min_bucket=cap)
+    jax.block_until_ready(inner.snapshot()[0].sstate.state.ratings)
+    t["state"] = time.monotonic() - t0
+    return Served(inner, sharded_view), ratings, spec, t
 
 
 def batch_shapes(engine_cfg, mix: dict):
@@ -391,7 +581,8 @@ def prepare(c, seed: int, *, require_chip=True, build_fn=None):
     gc.collect()
     gc.freeze()
     return types.SimpleNamespace(
-        dev=dev, devices=devices, counter=counter, stalls=stalls,
+        dev=dev, devices=devices, used=devices[:c.cell["chips"]],
+        counter=counter, stalls=stalls,
         engine_cfg=engine_cfg,
         backend=backend, ratings=ratings, spec=spec, t_setup=t_setup,
         landmarks=reflib.landmarks(ratings.counts(), spec.n_landmarks))
@@ -457,11 +648,21 @@ def drive(dep, c, seed: int, seconds: float, rate, trace: bool,
             log(f"trace: no capture ({tracing.error})")
         else:
             w.trace = trace_reduce.reduce(path)
+            win = w.trace["window_s"]
+            log(f"trace: window {win} s; device: busy s, idle % "
+                + json.dumps({k: [v, 100.0 * (1.0 - v / win)] for k, v in
+                              w.trace["device_busy_s"].items()}))
+            log("trace: collective ops, s over all devices "
+                + json.dumps({k: v for k, v in w.trace["ops"].items()
+                              if any(c in k for c in COLLECTIVES)}))
             log(f"trace: {path}, {os.path.getsize(path)} bytes, modules "
                 + json.dumps({k: [v, w.trace["module_runs"][k]]
                               for k, v in w.trace["modules"].items()}))
     w.stats = engine.stats()
-    w.mem = (dep.dev.memory_stats() or {}).get("peak_bytes_in_use", 0)
+    # the fullest of the cell's devices
+    peaks = {d.id: (d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+             for d in dep.used}
+    w.mem = max(peaks.values())
 
     # ---- what was served, and how late
     w.lat = {k: [] for k in trafficlib.READS + trafficlib.WRITES}
@@ -507,13 +708,11 @@ def drive(dep, c, seed: int, seconds: float, rate, trace: bool,
         f"{st['shed']}, {w.compiles} compiles or traces inside, "
         f"{st['batches']} batches of {st['mean_batch_rows']:.1f} rows, "
         f"generation {backend.generation}, {len(backend.kept)} kept")
-    log(f"device: peak_bytes_in_use {w.mem}")
+    log(f"device: peak_bytes_in_use {w.mem}, by device {json.dumps(peaks)}")
     log(f"gc: {len(pauses.gen2)} full collections in the window, longest "
         f"{max(pauses.gen2, default=0.0) * 1e3:.3f} ms")
     t_open = t_win - T_START
-    log(f"stalls: window {t_open:.3f}-{t_open + seconds:.3f} s after start; "
-        + ", ".join(f"{t:.3f} s +{d * 1e3:.0f} ms"
-                    for t, d in dep.stalls.seen))
+    dep.stalls.report(t_open, seconds)
 
     for kind, v in w.lat.items():
         if v:

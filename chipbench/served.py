@@ -86,6 +86,41 @@ def program_view(pub):
             mst.bstate.n_valid)
 
 
+class _Logical:
+    """A row-sharded array of a ``MutableShardedBackend`` generation, read
+    on the host in logical row order: ``rows[u]`` is logical row ``u``'s
+    sharded row (``shard * capacity + slot``, from the published tables).
+    With ``ids``, its values are sharded row ids too, read back as logical
+    ids (-1 for a row no logical id owns). The device array is held, not
+    copied: nothing runs until the check reads it."""
+
+    def __init__(self, arr, rows: np.ndarray, ids: bool = False):
+        self.arr, self.rows, self.ids = arr, rows, ids
+
+    def __array__(self, dtype=None, copy=None):
+        out = np.asarray(self.arr)[self.rows]
+        if self.ids:
+            logical = np.full(np.shape(self.arr)[0], -1, np.int64)
+            logical[self.rows] = np.arange(len(self.rows))
+            out = logical[out]
+        return out if dtype is None else out.astype(dtype)
+
+
+def sharded_view(pub):
+    """:func:`program_view` of a ``MutableShardedBackend`` generation: the
+    same five arrays, in logical id order through the generation's own
+    ``id_shard`` / ``id_slot`` tables, neighbour ids as logical ids, and
+    the logical row count."""
+    msst, id_shard, id_slot, _ = pub
+    st = msst.sstate.state
+    graph = st.graph.to_full() if st.graph.is_compact else st.graph
+    rows = id_shard.astype(np.int64) * msst.capacity + id_slot
+    return (_Logical(st.representation, rows),
+            _Logical(graph.indices, rows, ids=True),
+            _Logical(graph.weights, rows), _Logical(msst.tomb, rows),
+            len(rows))
+
+
 def state_arrays(view) -> dict:
     """Host copy of a kept view. The rows are cut on the host: a slice on
     the device would compile anew for every row count."""

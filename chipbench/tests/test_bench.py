@@ -245,3 +245,149 @@ def test_fit_on_the_pallas_graph_path_is_not_correct():
     out = run.run(c, SEED, 2.0, False, require_chip=False)
     assert not out["correct"], out["checks"]
     assert out["checks"]["order_err"]["value"] > 1, out["checks"]
+
+
+# ------------------------------------------------------- a sharded deployment
+# Four host devices exist only if XLA is told so before JAX starts, so the
+# sharded runs go to a child process; it prints one result line per run.
+SHARDED_RUNS = r"""
+import json, sys
+sys.path[:0] = sys.argv[1:3]
+from chipbench import run
+from chipbench.served import sharded_view
+from chipbench.tests.tiny import tiny_cell
+
+def broken_view(pub):
+    # logical row 0 read from row 1's slot
+    msst, shard, slot, gen = pub
+    shard, slot = shard.copy(), slot.copy()
+    shard[0], slot[0] = shard[1], slot[1]
+    return sharded_view((msst, shard, slot, gen))
+
+def build_broken(c, seed):
+    backend, ratings, spec, t = run.build(c, seed)
+    backend.view = broken_view
+    return backend, ratings, spec, t
+
+c = tiny_cell("read")
+c.cell = {"chips": 4}
+c.cfg["serving"]["backend"] = "MutableShardedBackend"
+for build_fn in (None, build_broken):
+    out = run.run(c, int(sys.argv[3]), 2.0, False, require_chip=False,
+                  build_fn=build_fn)
+    print(json.dumps(out), flush=True)
+"""
+
+
+@pytest.fixture(scope="module")
+def sharded_runs():
+    import os
+    import subprocess
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4")
+    done = subprocess.run(
+        [sys.executable, "-c", SHARDED_RUNS, str(ROOT), str(ROOT / "src"),
+         str(SEED)], env=env, capture_output=True, text=True, timeout=600)
+    assert done.returncode == 0, done.stderr[-4000:]
+    import json
+
+    program, broken = [json.loads(line) for line in
+                       done.stdout.strip().splitlines()[-2:]]
+    return program, broken
+
+
+def test_sharded_program_is_correct(sharded_runs):
+    """``MutableShardedBackend`` on a ``pod=4`` mesh: built, warmed,
+    driven and checked through the same path as one chip."""
+    out = sharded_runs[0]
+    assert out["device"]["count"] == 4
+    assert out["correct"], out["checks"]
+    assert out["failed"] == 0 and out["checks"]["rows_err"]["value"] == 0
+
+
+def test_sharded_view_with_a_wrong_slot_is_not_correct(sharded_runs):
+    out = sharded_runs[1]
+    assert not out["correct"], out["checks"]
+    failing = {k for k, v in out["checks"].items() if v["value"] > v["limit"]}
+    assert failing & {"rows_err", "rep_err", "weight_err"}, out["checks"]
+
+
+def test_local_build_makes_no_mesh(monkeypatch):
+    """A one-chip configuration is built as before: no mesh, one device."""
+    import jax
+    from jax.sharding import Mesh
+
+    from chipbench import run
+
+    made = []
+    new = Mesh.__new__
+
+    def counting_new(cls, *args, **kw):
+        made.append(args)
+        return new(cls, *args, **kw)
+
+    monkeypatch.setattr(Mesh, "__new__", counting_new)
+    backend, _, _, _ = run.build(tiny_cell("read"), SEED)
+    assert made == []
+    arrays = jax.tree_util.tree_leaves(backend.inner.snapshot()[0])
+    assert {d for a in arrays for d in a.devices()} == {jax.devices()[0]}
+
+
+def test_sharded_build_refuses_a_graph_path_it_cannot_take():
+    """``fit_distributed`` builds its graph on the streaming path only."""
+    from chipbench import run
+
+    c = tiny_cell("read")
+    c.cell = {"chips": 4}
+    c.cfg["serving"]["backend"] = "MutableShardedBackend"
+    c.cfg["fit_graph_backend"] = "pallas"
+    with pytest.raises(SystemExit, match="streaming path only"):
+        run.build(c, SEED)
+
+
+def test_stalls_name_the_thread_that_holds_the_interpreter_lock():
+    """A thread that holds the lock, blocked and then computing, stalls
+    the watcher: the witness process runs on, the computing thread's CPU
+    covers its stall and not the blocked one's, and the stacks found after
+    each stall name the holder's frame."""
+    import ctypes
+    import threading
+    import time
+
+    from chipbench import run
+
+    go = threading.Event()
+
+    def blocked_holder():
+        go.wait()
+        ctypes.PyDLL(None).usleep(200_000)  # holds the lock, no CPU
+        time.sleep(0.3)
+
+    def busy_holder():
+        go.wait()
+        time.sleep(0.6)
+        7 ** 900_000  # one C call, with the lock
+        time.sleep(0.3)
+
+    stalls = run.Stalls()
+    try:
+        threads = [threading.Thread(target=f, name=f.__name__)
+                   for f in (blocked_holder, busy_holder)]
+        for th in threads:
+            th.start()
+        time.sleep(0.3)  # the watcher has read each thread's clock
+        go.set()
+        for th in threads:
+            th.join()
+        stalls.report(0.0, 100.0)
+    finally:
+        stalls.stop()
+    long = [s for s in stalls.seen if s.lost >= 0.08]
+    assert len(long) == 2, [(s.at, s.lost) for s in stalls.seen]
+    blocked, busy = long
+    assert not blocked.witness and not busy.witness
+    assert blocked.threads["blocked_holder"] < 0.02
+    assert busy.threads["busy_holder"] > 0.5 * busy.lost
+    assert "in blocked_holder" in "".join(blocked.stacks["blocked_holder"])
+    assert "in busy_holder" in "".join(busy.stacks["busy_holder"])

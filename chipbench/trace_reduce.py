@@ -7,8 +7,9 @@ plane it takes the spans that the benchmark opened around its calls into
 the program (names starting with ``chipbench/``).
 
 - ``busy_s``: the union of the operation intervals, averaged over the
-  devices that ran anything; ``window_s``: from the first to the last
-  event of the capture, host and device.
+  devices that ran anything, and ``device_busy_s`` that of each device
+  plane by name; ``window_s``: from the first to the last event of the
+  capture, host and device.
 - ``ops``: device seconds per operation (the HLO name, ``%fusion.17``,
   without its text); ``modules``: device seconds
   per program name, and ``module_runs`` their count.
@@ -57,7 +58,7 @@ def reduce(path: str) -> dict:
     ops = collections.Counter()
     modules = collections.Counter()
     runs = collections.Counter()
-    busy, dev_iv, edges = [], [], []
+    busy, dev_iv, edges = {}, [], []
     host = []
     for plane in pd.planes:
         if plane.name.startswith("/device:TPU"):
@@ -75,7 +76,7 @@ def reduce(path: str) -> dict:
                         runs[name] += 1
             if iv:
                 u = _union(np.asarray(iv, np.float64))
-                busy.append(float((u[:, 1] - u[:, 0]).sum()) * 1e-9)
+                busy[plane.name] = float((u[:, 1] - u[:, 0]).sum()) * 1e-9
                 dev_iv.append(u)
                 edges += [u[0, 0], u[-1, 1]]
         elif plane.name.startswith("/host:CPU"):
@@ -86,12 +87,14 @@ def reduce(path: str) -> dict:
                         host.append((ev.start_ns, ev.start_ns
                                      + ev.duration_ns, ev.name))
     if not dev_iv:
-        return {"busy_s": 0.0, "window_s": 0.0, "ops": {}, "modules": {},
+        return {"busy_s": 0.0, "device_busy_s": {}, "window_s": 0.0,
+                "ops": {}, "modules": {},
                 "module_runs": {}, "gaps": {}, "annotations": []}
     t0, t1 = min(edges), max(edges)
     host.sort()
     return {
-        "busy_s": float(np.mean(busy)),
+        "busy_s": float(np.mean(list(busy.values()))),
+        "device_busy_s": busy,
         "window_s": (t1 - t0) * 1e-9,
         "ops": dict(ops),
         "modules": dict(modules),
